@@ -12,6 +12,8 @@ import json
 import sys
 import traceback
 
+from repro.launch.compile_cache import setup_compile_cache
+
 MODULES = [
     "benchmarks.bench_superres",    # §5.2 / fig. 7
     "benchmarks.bench_lenet",       # §5.3 / figs. 8-9
@@ -34,6 +36,7 @@ def main() -> None:
                     help="also write results as JSON "
                          "(name → us_per_call + derived)")
     args = ap.parse_args()
+    setup_compile_cache()
 
     only = args.only.split(",") if args.only else None
     if only:

@@ -37,8 +37,8 @@ export XLA_FLAGS="${XLA_FLAGS:+$XLA_FLAGS }--xla_cpu_parallel_codegen_split_coun
 # build at the kernel oracle, not in an end-to-end stream diff.  Then
 # the rest of tier-1.  With extra pytest args, fall back to one plain
 # invocation so -k/--lf/-m filters keep applying to everything.
-# Mosaic-only tests carry the `tpu` marker and auto-skip on CPU (run
-# them on hardware with: pytest -m tpu).
+# tests/test_tpu_compile.py compiles the served kernels for a described
+# TPU v5e (libtpu, no chip); it skips where libtpu cannot describe one.
 if [ "$#" -gt 0 ]; then
     PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -x -q "$@"
 else

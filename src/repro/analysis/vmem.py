@@ -51,15 +51,13 @@ PAGED_Q_SIDE_FLOATS = 128 * 512
 
 
 def estimate_vmem_bytes(kind: str, bm: int, bn: int, bk: int, bits: int,
-                        k: int, *, order: str = "kd",
-                        dequant: str = "lut") -> int:
+                        k: int, *, order: str = "kd") -> int:
     """Per-grid-step VMEM bytes a kernel asks Mosaic to resident-fit.
 
     Mirrors the BlockSpecs in ``kernels/codebook_matmul_packed{,_t}.py``
     and ``kernels/quantized_gather.py``: DMA'd input/output blocks count
     ×2 (pipeline double buffering); the in-kernel unpack index tile and
-    dequantized weight tile count once (``dequant="onehot"`` adds the
-    [*, K] one-hot instead of the LUT result).
+    dequantized weight tile count once.
     """
     lanes = 32 // bits
     f32, u32, i32 = 4, 4, 4
@@ -82,16 +80,12 @@ def estimate_vmem_bytes(kind: str, bm: int, bn: int, bk: int, bits: int,
     else:
         raise ValueError(f"kind={kind!r}; choose from {KINDS}")
     body = tile[0] * tile[1] * i32                       # unpacked indices
-    if dequant == "onehot":
-        body += tile[0] * tile[1] * k * f32              # one-hot tensor
-    else:
-        body += tile[0] * tile[1] * f32                  # LUT result tile
+    body += tile[0] * tile[1] * f32                      # dequantized tile
     return 2 * dma + body
 
 
 def validate_block_config(kind: str, bm: int, bn: int, bk: int, bits: int,
                           k: int, *, order: str = "kd",
-                          dequant: str = "lut",
                           budget: int = VMEM_BUDGET) -> Dict[str, Any]:
     """Statically lint one block config; returns
     ``{"ok", "errors", "warnings", "vmem_bytes"}``.  ``errors`` are
@@ -124,8 +118,7 @@ def validate_block_config(kind: str, bm: int, bn: int, bk: int, bits: int,
             if v % 128:
                 warnings.append(f"{name}={v} not 128-lane aligned — "
                                 f"padded tiles waste VPU/MXU width")
-    vmem = estimate_vmem_bytes(kind, bm, bn, bk, bits, k, order=order,
-                               dequant=dequant)
+    vmem = estimate_vmem_bytes(kind, bm, bn, bk, bits, k, order=order)
     if vmem > budget:
         errors.append(f"~{vmem / 2**20:.1f} MiB/step exceeds the "
                       f"{budget / 2**20:.1f} MiB VMEM budget "
@@ -161,8 +154,7 @@ def _leaf_block_configs(leaf: str, lay: PackedLayout
                    "m": m, "order": "kd"}
 
 
-def audit_block_space(protected: Dict[str, dict],
-                      dequant: str = "lut") -> Dict[str, Any]:
+def audit_block_space(protected: Dict[str, dict]) -> Dict[str, Any]:
     """Sweep every block config reachable from the autotune surface.
 
     ``protected`` is :func:`repro.analysis.graph.protected_leaves`
@@ -195,8 +187,7 @@ def audit_block_space(protected: Dict[str, dict],
     for job in jobs:
         bm, bn, bk = job["blocks"]
         res = validate_block_config(job["kind"], bm, bn, bk, job["bits"],
-                                    job["k"], order=job["order"],
-                                    dequant=dequant)
+                                    job["k"], order=job["order"])
         rows.append({**job, **res})
         for err in res["errors"]:
             violations.append({
@@ -217,17 +208,15 @@ def block_table_entries() -> Dict[Tuple[int, int, int, int],
 # ---------------------------------------------------------------------------
 
 def estimate_paged_vmem_bytes(kind: str, feat: int, page_size: int,
-                              token_tile: int, bits: int, *,
-                              dequant: str = "lut") -> int:
+                              token_tile: int, bits: int) -> int:
     """Per-grid-step VMEM bytes a paged kernel asks Mosaic to fit.
 
     Mirrors the BlockSpecs in ``kernels/paged_attention.py``: per step
     one ``token_tile``-token KV tile per cached tensor is DMA'd (×2 for
     double buffering) — dense f32 rows, or packed uint32 words plus the
     per-page codebooks when ``bits`` — and the quant kernel bodies
-    create the unpacked index tile + the dequantized f32 tile (``lut``)
-    or the [*, K] one-hot (``onehot``).  The query side (q/out blocks +
-    m/l/acc online-softmax scratch) is bounded by
+    create the unpacked index tile + the dequantized f32 tile.  The
+    query side (q/out blocks + m/l/acc online-softmax scratch) is bounded by
     :data:`PAGED_Q_SIDE_FLOATS` rather than threaded per-config — it is
     token-tile independent and small against the budget.
     """
@@ -243,8 +232,6 @@ def estimate_paged_vmem_bytes(kind: str, feat: int, page_size: int,
         kv_tile = bt * words * u32 + k * f32      # word tile + codebook
         body = n_tensors * (bt * feat * i32       # unpacked index tile
                             + bt * feat * f32)    # dequantized KV tile
-        if dequant == "onehot":
-            body += n_tensors * bt * feat * k * f32
     else:
         kv_tile = bt * feat * f32
         body = 0
@@ -262,7 +249,6 @@ def estimate_paged_vmem_bytes(kind: str, feat: int, page_size: int,
 
 def validate_paged_block_config(kind: str, feat: int, page_size: int,
                                 token_tile: int, bits: int, *,
-                                dequant: str = "lut",
                                 budget: int = VMEM_BUDGET
                                 ) -> Dict[str, Any]:
     """Statically lint one paged-route token-tile config; same contract
@@ -288,8 +274,7 @@ def validate_paged_block_config(kind: str, feat: int, page_size: int,
         warnings.append(f"feat={feat} not 128-lane aligned — Mosaic pads "
                         f"the KV tile's trailing dim")
     vmem = estimate_paged_vmem_bytes(kind, feat, page_size,
-                                     max(token_tile, 1), bits,
-                                     dequant=dequant)
+                                     max(token_tile, 1), bits)
     if vmem > budget:
         errors.append(f"~{vmem / 2**20:.1f} MiB/step exceeds the "
                       f"{budget / 2**20:.1f} MiB VMEM budget "
@@ -301,7 +286,7 @@ def validate_paged_block_config(kind: str, feat: int, page_size: int,
             "vmem_bytes": vmem}
 
 
-def audit_paged_block_space(dequant: str = "lut") -> Dict[str, Any]:
+def audit_paged_block_space() -> Dict[str, Any]:
     """Sweep every committed ``dispatch._PAGED_BLOCK_TABLE`` entry — the
     paged-route analogue of :func:`audit_block_space`.  A bad token tile
     otherwise only fails at Mosaic compile time on a TPU."""
@@ -310,8 +295,7 @@ def audit_paged_block_space(dequant: str = "lut") -> Dict[str, Any]:
     for (kind, feat, page, bits), tile in sorted(
             dispatch.paged_block_table().items()):
         source = f"paged_table[{kind},{feat},{page},{bits}]"
-        res = validate_paged_block_config(kind, feat, page, tile, bits,
-                                          dequant=dequant)
+        res = validate_paged_block_config(kind, feat, page, tile, bits)
         rows.append({"kind": kind, "feat": feat, "page_size": page,
                      "bits": bits, "token_tile": tile, "source": source,
                      **res})
@@ -340,8 +324,7 @@ PREFILL_HEADS_BOUND = 32
 
 
 def estimate_prefill_vmem_bytes(kind: str, feat: int, token_tile: int,
-                                bits: int = 0, *,
-                                dequant: str = "lut") -> int:
+                                bits: int = 0) -> int:
     """Per-grid-step VMEM bytes a blockwise-prefill kernel asks Mosaic
     to fit.
 
@@ -365,8 +348,6 @@ def estimate_prefill_vmem_bytes(kind: str, feat: int, token_tile: int,
         kv_tile = bt * kv * words * u32 + kent * f32
         # unpack index tile + dequantized f32 tile, for K and for V
         body = 2 * bt * kv * feat * (i32 + f32)
-        if dequant == "onehot":
-            body += 2 * bt * kv * feat * kent * f32
     else:
         kv_tile = bt * kv * feat * f32
         body = 0
@@ -377,7 +358,7 @@ def estimate_prefill_vmem_bytes(kind: str, feat: int, token_tile: int,
 
 
 def validate_prefill_block_config(kind: str, feat: int, token_tile: int,
-                                  bits: int = 0, *, dequant: str = "lut",
+                                  bits: int = 0, *,
                                   budget: int = VMEM_BUDGET
                                   ) -> Dict[str, Any]:
     """Statically lint one blockwise-prefill token-tile entry; same
@@ -403,7 +384,7 @@ def validate_prefill_block_config(kind: str, feat: int, token_tile: int,
         warnings.append(f"feat={feat} not 128-lane aligned — Mosaic pads "
                         f"the KV tile's trailing dim")
     vmem = estimate_prefill_vmem_bytes(kind, feat, max(token_tile, 1),
-                                       bits, dequant=dequant)
+                                       bits)
     if vmem > budget:
         errors.append(f"~{vmem / 2**20:.1f} MiB/step exceeds the "
                       f"{budget / 2**20:.1f} MiB VMEM budget "
@@ -415,7 +396,7 @@ def validate_prefill_block_config(kind: str, feat: int, token_tile: int,
             "vmem_bytes": vmem}
 
 
-def audit_prefill_block_space(dequant: str = "lut") -> Dict[str, Any]:
+def audit_prefill_block_space() -> Dict[str, Any]:
     """Sweep every committed ``dispatch._PREFILL_BLOCK_TABLE`` entry —
     quant entries at every supported ``kv_bits`` (the table doesn't key
     on bits; the worst case must still fit)."""
@@ -425,8 +406,7 @@ def audit_prefill_block_space(dequant: str = "lut") -> Dict[str, Any]:
         source = f"prefill_table[{kind},{feat}]"
         sweep = kvquant.KV_BITS_CHOICES if kind == "quant" else (0,)
         for bits in sweep:
-            res = validate_prefill_block_config(kind, feat, tile, bits,
-                                                dequant=dequant)
+            res = validate_prefill_block_config(kind, feat, tile, bits)
             rows.append({"kind": kind, "feat": feat, "bits": bits,
                          "token_tile": tile, "source": source, **res})
             for err in res["errors"]:

@@ -18,12 +18,13 @@ Supports:
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.quant_ops import fixed_codebook_assign
+from repro.core.quant_ops import MASKED_MAX_K, fixed_codebook_assign
 
 Array = jax.Array
 
@@ -70,6 +71,35 @@ def quantile_init(w: Array, k: int) -> Array:
     return jnp.quantile(w.ravel().astype(jnp.float32), qs).astype(w.dtype)
 
 
+@functools.partial(jax.jit, static_argnames="k")
+def cluster_sums(values: Array, assign: Array, k: int) -> Array:
+    """[k] per-cluster sums of ``values`` over ids ``assign`` in [0, k).
+
+    Up to ``MASKED_MAX_K`` clusters: a masked reduction XLA fuses into
+    one pass — a scatter-add with k ≪ N colliding ids serializes on a
+    TPU (jit keeps the [N, K] mask fused when this is called eagerly).
+    Larger k keeps the scatter-add.
+    """
+    if k > MASKED_MAX_K:
+        return jax.ops.segment_sum(values.ravel(), assign.ravel(),
+                                   num_segments=k)
+    hit = assign[..., None] == jnp.arange(k, dtype=assign.dtype)
+    return jnp.sum(jnp.where(hit, values[..., None], 0),
+                   axis=tuple(range(values.ndim)))
+
+
+@jax.jit
+def codebook_lookup(c: Array, assign: Array) -> Array:
+    """``c[assign]``; up to ``MASKED_MAX_K`` entries as a fused masked
+    sum (exact: one non-zero term per weight) instead of a per-weight
+    gather."""
+    if c.shape[0] > MASKED_MAX_K:
+        return c[assign]
+    hit = assign[..., None] == jnp.arange(c.shape[0], dtype=assign.dtype)
+    return jnp.sum(jnp.where(hit, c, 0), axis=-1)
+
+
+@functools.partial(jax.jit, static_argnames=("iters", "axis_name", "tol"))
 def kmeans_fit(
     w: Array,
     init_codebook: Array,
@@ -101,8 +131,8 @@ def kmeans_fit(
     def step(carry, _):
         c, prev_assign, prev_dist, done, n_run = carry
         assign = fixed_codebook_assign(flat, c)
-        sums = psum(jax.ops.segment_sum(flat * nw, assign, num_segments=k))
-        counts = psum(jax.ops.segment_sum(nw, assign, num_segments=k))
+        sums = psum(cluster_sums(flat * nw, assign, k))
+        counts = psum(cluster_sums(nw, assign, k))
         c_new = jnp.where(counts > 0, sums / jnp.maximum(counts, 1), c)
         c_new = jnp.sort(c_new)
         # Convergence must be GLOBAL: a shard whose local assignments are
@@ -113,7 +143,7 @@ def kmeans_fit(
         changed = psum(changed) > 0
         # f32 accumulation: the carry slot is f32, and bf16 would both
         # break the scan carry-type match and swamp the plateau test.
-        resid = (flat - c[assign]).astype(jnp.float32)
+        resid = (flat - codebook_lookup(c, assign)).astype(jnp.float32)
         dist = psum(jnp.sum(nw.astype(jnp.float32) * resid * resid))
         plateau = (prev_dist - dist) <= tol * jnp.abs(dist)
         # Freeze once converged so iters_run is the true fixpoint index.
@@ -129,7 +159,7 @@ def kmeans_fit(
     (c, _, _, _, n_run), _ = jax.lax.scan(step, init, None, length=iters)
 
     assign = fixed_codebook_assign(flat, c)
-    resid = (flat - c[assign]).astype(jnp.float32)
+    resid = (flat - codebook_lookup(c, assign)).astype(jnp.float32)
     dist = psum(jnp.sum(nw.astype(jnp.float32) * resid * resid))
     return KMeansResult(c, assign.reshape(w.shape), dist, n_run)
 

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.core import quant_ops
 from repro.core.kmeans import (
+    codebook_lookup,
     kmeans_fit,
     kmeans_plus_plus_init,
     kmeans_quantize,
@@ -125,7 +126,7 @@ class AdaptiveScheme(Scheme):
     def c_step(self, w, state, first=False):
         iters = self.iters_first if first else self.iters_warm
         res = kmeans_fit(w, state["codebook"], iters=iters)
-        q = res.codebook[res.assignments]
+        q = codebook_lookup(res.codebook, res.assignments)
         return q.astype(w.dtype), {"codebook": res.codebook,
                                    "kmeans_iters": res.iters_run}
 

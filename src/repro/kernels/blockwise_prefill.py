@@ -49,6 +49,14 @@ def _softcap(x, cap):
     return cap * jnp.tanh(x / cap)
 
 
+def _positions(q_pos, k_pos, bt):
+    """Position operands in (8, 128)-legal block shapes: q_pos as one
+    [C, 1] column, k_pos as [S/bt, 1, bt] so a KV tile's positions are
+    one whole trailing [1, bt] block."""
+    return (q_pos.astype(jnp.int32).reshape(-1, 1),
+            k_pos.astype(jnp.int32).reshape(-1, 1, bt))
+
+
 def _prefill_body(q_ref, k, v, qp_ref, kp_ref, o_ref, m_ref, l_ref,
                   acc_ref, *, j, nj, window, softcap, scale):
     """Shared tile step.  k/v: [bt, KV, hd/vd] f32 (already dequant).
@@ -68,11 +76,11 @@ def _prefill_body(q_ref, k, v, qp_ref, kp_ref, o_ref, m_ref, l_ref,
         qg, kt, (((3,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * scale
     logits = _softcap(logits, softcap)
-    qpos = qp_ref[0]                                 # [C]
-    kpos = kp_ref[0]                                 # [bt]
-    ok = kpos[None, :] <= qpos[:, None]
+    qpos = qp_ref[...]                               # [C, 1]
+    kpos = kp_ref[0]                                 # [1, bt]
+    ok = kpos <= qpos
     if window is not None:
-        ok &= (qpos[:, None] - kpos[None, :]) < window
+        ok &= (qpos - kpos) < window
     ok = jnp.broadcast_to(ok[None, None, :, :], logits.shape)
     logits = jnp.where(ok, logits, NEG_INF)
     m_prev = m_ref[...]
@@ -111,7 +119,7 @@ def _prefill_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref, m_ref,
 
 def _prefill_quant_kernel(q_ref, kw_ref, vw_ref, kcb_ref, vcb_ref, qp_ref,
                           kp_ref, o_ref, m_ref, l_ref, acc_ref, *, nj,
-                          window, softcap, scale, head_dim, bits, dequant):
+                          window, softcap, scale, head_dim, bits):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -121,9 +129,9 @@ def _prefill_quant_kernel(q_ref, kw_ref, vw_ref, kcb_ref, vcb_ref, qp_ref,
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     k = _dequant_kv_tile(kw_ref[0], kcb_ref[0, 0], head_dim=head_dim,
-                         bits=bits, dequant=dequant)
+                         bits=bits)
     v = _dequant_kv_tile(vw_ref[0], vcb_ref[0, 0], head_dim=head_dim,
-                         bits=bits, dequant=dequant)
+                         bits=bits)
     _prefill_body(q_ref, k, v, qp_ref, kp_ref, o_ref, m_ref, l_ref,
                   acc_ref, j=j, nj=nj, window=window, softcap=softcap,
                   scale=scale)
@@ -152,8 +160,8 @@ def blockwise_prefill_pallas(q, k, v, q_pos, k_pos, *, window=None,
             pl.BlockSpec((1, c, h, hd), lambda b, j: (b, 0, 0, 0)),
             pl.BlockSpec((1, bt, kv, hd), lambda b, j: (b, j, 0, 0)),
             pl.BlockSpec((1, bt, kv, vd), lambda b, j: (b, j, 0, 0)),
-            pl.BlockSpec((1, c), lambda b, j: (0, 0)),
-            pl.BlockSpec((1, bt), lambda b, j: (0, j)),
+            pl.BlockSpec((c, 1), lambda b, j: (0, 0)),
+            pl.BlockSpec((1, 1, bt), lambda b, j: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, c, h, vd), lambda b, j: (b, 0, 0, 0)),
         scratch_shapes=[
@@ -168,16 +176,14 @@ def blockwise_prefill_pallas(q, k, v, q_pos, k_pos, *, window=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c, h, vd), jnp.float32),
         interpret=interpret,
-    )(q, k, v, q_pos.astype(jnp.int32)[None, :],
-      k_pos.astype(jnp.int32)[None, :])
+    )(q, k, v, *_positions(q_pos, k_pos, bt))
     return out
 
 
 def blockwise_prefill_quant_pallas(q, k_words, v_words, k_cb, v_cb, q_pos,
                                    k_pos, *, page_size, bits, head_dim,
                                    window=None, softcap=None, scale,
-                                   token_tile, dequant="lut",
-                                   interpret=False):
+                                   token_tile, interpret=False):
     """Quantized-page view: words [B, S, KV, Wd] uint32 (S = pages·page,
     logical row order) + per-page codebooks [B, npg, Gcb, K]
     → [B, C, H, hd] f32.  ``token_tile`` must divide ``page_size`` so a
@@ -209,8 +215,8 @@ def blockwise_prefill_quant_pallas(q, k_words, v_words, k_cb, v_cb, q_pos,
                          lambda b, j: (b, j // tpp, 0, 0)),
             pl.BlockSpec((1, 1, gcb, k_ent),
                          lambda b, j: (b, j // tpp, 0, 0)),
-            pl.BlockSpec((1, c), lambda b, j: (0, 0)),
-            pl.BlockSpec((1, bt), lambda b, j: (0, j)),
+            pl.BlockSpec((c, 1), lambda b, j: (0, 0)),
+            pl.BlockSpec((1, 1, bt), lambda b, j: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, c, h, hd), lambda b, j: (b, 0, 0, 0)),
         scratch_shapes=[
@@ -222,10 +228,9 @@ def blockwise_prefill_quant_pallas(q, k_words, v_words, k_cb, v_cb, q_pos,
     out = pl.pallas_call(
         functools.partial(_prefill_quant_kernel, nj=nj, window=window,
                           softcap=softcap, scale=scale, head_dim=head_dim,
-                          bits=bits, dequant=dequant),
+                          bits=bits),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c, h, hd), jnp.float32),
         interpret=interpret,
-    )(q, k_words, v_words, k_cb, v_cb,
-      q_pos.astype(jnp.int32)[None, :], k_pos.astype(jnp.int32)[None, :])
+    )(q, k_words, v_words, k_cb, v_cb, *_positions(q_pos, k_pos, bt))
     return out

@@ -11,13 +11,8 @@ weight traffic per step drops from 2 bytes/weight (bf16) to 1 byte
 (uint8 idx; 4-bit packing halves it again — see ops.py), which directly
 scales the decode-shape memory term (§Roofline).
 
-Dequant strategy: a K-entry LUT gather ``cb[idx]`` (``dequant="lut"``, the
-default) — O(bk·bn) independent of K, so a K=256 adaptive codebook costs
-the same per tile as K=4.  ``dequant="onehot"`` keeps the original
-MXU-shaped one-hot contraction ``W_tile = onehot(idx) @ codebook``
-(O(bk·bn·K)) as a fallback for Mosaic versions that lower small-table
-gathers poorly (flip globally via ``REPRO_DEQUANT=onehot`` — see
-dispatch.py).
+Dequant: ``kernels.unpack.dequant_tile``, a compare+select chain over
+the K codebook entries (Mosaic refuses small-table gathers).
 
 Grid: (M/bm, N/bn, Kd/bk), k innermost; f32 accumulation directly in the
 revisited output block (sequential TPU grid ⇒ safe).
@@ -37,8 +32,7 @@ from jax.experimental import pallas as pl
 from repro.kernels.unpack import dequant_tile
 
 
-def _kernel(x_ref, idx_ref, cb_ref, o_ref, *, k_entries: int, bk: int,
-            bn: int, dequant: str):
+def _kernel(x_ref, idx_ref, cb_ref, o_ref, *, bk: int, bn: int):
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
@@ -47,9 +41,7 @@ def _kernel(x_ref, idx_ref, cb_ref, o_ref, *, k_entries: int, bk: int,
 
     x = x_ref[...]                                    # [bm, bk]
     idx = idx_ref[...].astype(jnp.int32)              # [bk, bn] uint8/int32
-    cb = cb_ref[0, :]                                 # [K]
-
-    w = dequant_tile(idx, cb, k_entries, dequant)    # [bk, bn]
+    w = dequant_tile(idx, cb_ref[...])                # [bk, bn]
     o_ref[...] += jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
                           preferred_element_type=jnp.float32)
 
@@ -60,7 +52,6 @@ def codebook_matmul_pallas(
     codebook: jax.Array,     # [K] float
     *,
     bm: int = 128, bn: int = 128, bk: int = 512,
-    dequant: str = "lut",
     interpret: bool = False,
 ) -> jax.Array:
     m, kd = x.shape
@@ -73,11 +64,8 @@ def codebook_matmul_pallas(
     ip = jnp.pad(idx, ((0, pk), (0, pn)))
     gm, gn, gk = xp.shape[0] // bm, ip.shape[1] // bn, xp.shape[1] // bk
 
-    if dequant not in ("lut", "onehot"):
-        raise ValueError(f"dequant={dequant!r}; choose lut|onehot")
     out = pl.pallas_call(
-        functools.partial(_kernel, k_entries=k_entries, bk=bk, bn=bn,
-                          dequant=dequant),
+        functools.partial(_kernel, bk=bk, bn=bn),
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

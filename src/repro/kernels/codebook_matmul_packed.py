@@ -13,11 +13,8 @@ step is exactly ``bits/8`` bytes/weight — 4 bits at K=16 (8× less than
 bf16, 2× less than the uint8-index layout), 2 bits at ternary, 1 bit at
 binary — plus one K-entry codebook reread per (i, j) tile.
 
-Dequant strategy: a K-entry LUT gather ``cb[idx]`` (``dequant="lut"``, the
-default) — O(bk·bn) independent of K, so a K=256 adaptive codebook serves
-at the same cost as K=4.  ``dequant="onehot"`` keeps the MXU-shaped
-one-hot contraction (O(bk·bn·K)) as a fallback for Mosaic versions that
-lower small-table gathers poorly (see ``REPRO_DEQUANT`` in dispatch.py).
+Dequant: ``kernels.unpack.dequant_tile``, a compare+select chain over
+the K codebook entries (Mosaic refuses small-table gathers).
 
 Grid: (M/bm, N/bn, Kd/bk), k innermost; f32 accumulation in the revisited
 output block (sequential TPU grid ⇒ safe).  ``bk`` must be a multiple of
@@ -35,8 +32,7 @@ from repro.core.compression import bits_per_index
 from repro.kernels.unpack import dequant_tile, unpack_words_axis0
 
 
-def _kernel(x_ref, pidx_ref, cb_ref, o_ref, *, k_entries: int, bits: int,
-            dequant: str):
+def _kernel(x_ref, pidx_ref, cb_ref, o_ref, *, bits: int):
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
@@ -45,11 +41,10 @@ def _kernel(x_ref, pidx_ref, cb_ref, o_ref, *, k_entries: int, bits: int,
 
     x = x_ref[...]                                    # [bm, bk]
     words = pidx_ref[...]                             # [bkw, bn] uint32
-    cb = cb_ref[0, :]                                 # [K]
 
     # In-VMEM unpack: word (w, n) → lanes indices at rows w·lanes+l.
     idx = unpack_words_axis0(words, bits)             # [bk, bn]
-    w = dequant_tile(idx, cb, k_entries, dequant)
+    w = dequant_tile(idx, cb_ref[...])
     o_ref[...] += jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
                           preferred_element_type=jnp.float32)
 
@@ -60,7 +55,6 @@ def codebook_matmul_packed_pallas(
     codebook: jax.Array,     # [K] float
     *,
     bm: int = 128, bn: int = 128, bk: int = 512,
-    dequant: str = "lut",
     interpret: bool = False,
 ) -> jax.Array:
     m, kd = x.shape
@@ -74,8 +68,6 @@ def codebook_matmul_packed_pallas(
     if bk % lanes:
         raise ValueError(f"bk={bk} must be a multiple of lanes={lanes} "
                          f"(bits={bits}) so word tiles don't straddle")
-    if dequant not in ("lut", "onehot"):
-        raise ValueError(f"dequant={dequant!r}; choose lut|onehot")
     bkw = bk // lanes
 
     # Pad M/N with zeros and Kd up to a bk multiple.  Padded x rows are
@@ -86,8 +78,7 @@ def codebook_matmul_packed_pallas(
     gm, gn, gk = xp.shape[0] // bm, pp.shape[1] // bn, kdp // bk
 
     out = pl.pallas_call(
-        functools.partial(_kernel, k_entries=k_entries, bits=bits,
-                          dequant=dequant),
+        functools.partial(_kernel, bits=bits),
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -39,8 +39,7 @@ from repro.kernels.unpack import (dequant_tile, unpack_words_axis0,
                                   unpack_words_axis1)
 
 
-def _kernel(x_ref, pidx_ref, cb_ref, o_ref, *, k_entries: int, bits: int,
-            order: str, dequant: str):
+def _kernel(x_ref, pidx_ref, cb_ref, o_ref, *, bits: int, order: str):
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
@@ -49,7 +48,6 @@ def _kernel(x_ref, pidx_ref, cb_ref, o_ref, *, k_entries: int, bits: int,
 
     x = x_ref[...]                                    # [bm, bk]
     words = pidx_ref[...]                             # see below
-    cb = cb_ref[0, :]                                 # [K]
 
     if order == "kd":
         # words [bnw, bk]: lanes expand along the V (output) axis.
@@ -57,7 +55,7 @@ def _kernel(x_ref, pidx_ref, cb_ref, o_ref, *, k_entries: int, bits: int,
     else:
         # words [bn, bkw]: lanes expand along the D (reduction) axis.
         idx = unpack_words_axis1(words, bits)         # [bn, bk]
-    w = dequant_tile(idx, cb, k_entries, dequant)     # [bn, bk]
+    w = dequant_tile(idx, cb_ref[...])                # [bn, bk]
     # y[bm, bn] += x[bm, bk] · w[bn, bk].T — contract the D axis.
     o_ref[...] += jax.lax.dot_general(
         x.astype(jnp.float32), w.astype(jnp.float32),
@@ -74,15 +72,12 @@ def codebook_matmul_packed_t_pallas(
     *,
     order: str = "kd",
     bm: int = 128, bn: int = 128, bk: int = 512,
-    dequant: str = "lut",
     interpret: bool = False,
 ) -> jax.Array:
     m, d = x.shape
     k_entries = codebook.shape[0]
     bits = bits_per_index(k_entries)
     lanes = 32 // bits
-    if dequant not in ("lut", "onehot"):
-        raise ValueError(f"dequant={dequant!r}; choose lut|onehot")
     if order not in ("kd", "row"):
         raise ValueError(f"order={order!r}; choose kd|row")
 
@@ -118,8 +113,7 @@ def codebook_matmul_packed_t_pallas(
 
     gm, gn, gk = xp.shape[0] // bm, vp // bn, dp // bk
     out = pl.pallas_call(
-        functools.partial(_kernel, k_entries=k_entries, bits=bits,
-                          order=order, dequant=dequant),
+        functools.partial(_kernel, bits=bits, order=order),
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -19,33 +19,51 @@ embedding gather here; this module picks the implementation:
 Default: pallas on TPU, ref elsewhere; override with
 ``REPRO_KERNEL_BACKEND=pallas|pallas_interpret|ref`` or per call.
 
-Dequant strategy inside the Pallas kernels is a K-entry LUT gather by
-default; ``REPRO_DEQUANT=onehot`` falls back to the one-hot contraction
-(O(K) per weight — the pre-LUT behaviour) for Mosaic versions that lower
-small-table gathers poorly.
-
 Block-size autotune (``packed_block_sizes``): the packed route picks
 (bm, bn, bk) from a shape-keyed table — exact (M, Kd, N, bits) entries
 first, then a roofline heuristic that separates decode shapes (M small:
 one activation tile, stream the packed weights with wide bn·bk tiles)
 from prefill shapes (M large: MXU-balanced 128×128×512).  Override with
 ``REPRO_PACKED_BLOCKS=bm,bn,bk``.
+
+Meshes: a Mosaic kernel cannot be partitioned by the compiler, so on a
+mesh of more than one device each Pallas route runs inside a
+``shard_map`` whose specs this module picks per kernel from the
+``mesh`` the model call site passes: matmuls split their weight's
+output (``split="col"``) or reduction (``"row"``, psum'd) axis over
+``model``, the LM head and the embedding gather split the vocabulary,
+attention splits heads.  Where a dim does not divide the ``model`` axis
+the operands replicate (each device runs the whole call).  Batch dims
+always replicate.  The ``ref`` backend is plain jnp and is left to the
+compiler.
 """
 from __future__ import annotations
 
+import collections
+import dataclasses
+import functools
+import math
 import os
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.compression import (PackedLayout, bits_per_index,
                                     unpack_indices_2d, unpack_rows)
 from repro.kernels import ops, ref
+from repro.kernels.quantized_gather import ROW_WORDS
 
 Array = jax.Array
 
 _BACKENDS = ("pallas", "pallas_interpret", "ref")
+
+# jnp routes taken while a Pallas backend is selected, tallied when each
+# call is traced: "dequant_dot" builds a dense weight before the dot,
+# "row_gather" gathers packed word rows in XLA (the kernel's bytes, no
+# dense table).  A chip run prints it; a dense model should show none.
+FALLBACKS: collections.Counter = collections.Counter()
 
 
 def default_backend() -> str:
@@ -58,11 +76,44 @@ def default_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
-def default_dequant() -> str:
-    env = os.environ.get("REPRO_DEQUANT", "lut")
-    if env not in ("lut", "onehot"):
-        raise ValueError(f"REPRO_DEQUANT={env!r}; choose lut|onehot")
-    return env
+def _model_size(mesh: Optional[Mesh], backend: str) -> int:
+    """0 when the call runs unmapped (no mesh, one device, or the ``ref``
+    backend); else the size of the mesh's ``model`` axis (1 if absent:
+    every operand replicates)."""
+    if mesh is None or mesh.size == 1 or backend == "ref":
+        return 0
+    return dict(mesh.shape).get("model", 1)
+
+
+def _shard_call(mesh: Mesh, body, in_specs, out_specs, *args):
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
+def _split_matmul(mesh: Mesh, m: int, split: Optional[str], rows_ok: bool,
+                  fn, x: Array, w: Array, cb: Array) -> Array:
+    """``fn(x, w, cb)`` = x[..., Kd] · W[Kd, N] inside a ``shard_map`` on
+    ``mesh``; ``w`` is W's stored operand, reduction rows first.
+    ``split`` is the W axis the ``model`` axis holds: "col" splits N (the
+    product comes out split the same way), "row" splits Kd, x's last
+    axis with it, and psums the partial products (when ``rows_ok``: the
+    split falls on word boundaries); anything else replicates."""
+    lead = (None,) * (x.ndim - 1)
+    if m > 1 and split == "col" and w.shape[-1] % m == 0:
+        return _shard_call(mesh, fn, (P(), P(None, "model"), P()),
+                           P(*lead, "model"), x, w, cb)
+    if m > 1 and split == "row" and rows_ok:
+        return _shard_call(
+            mesh, lambda x, w, c: jax.lax.psum(fn(x, w, c), "model"),
+            (P(*lead, "model"), P("model", None), P()), P(), x, w, cb)
+    return _shard_call(mesh, fn, (P(), P(), P()), P(), x, w, cb)
+
+
+def _heads_spec(ndim: int, axis: int, split: bool) -> P:
+    """``model`` on ``axis`` of an ``ndim`` operand (replicated if not
+    ``split``)."""
+    return P(*["model" if split and d == axis else None
+               for d in range(ndim)])
 
 
 # ---------------------------------------------------------------------------
@@ -96,6 +147,19 @@ def _round_up(v: int, mult: int) -> int:
     return -(-v // mult) * mult
 
 
+def _fit_block(block: int, dim: int, quantum: int) -> int:
+    """The largest multiple of ``quantum`` ≤ ``block`` that divides
+    ``dim`` — so the kernel never pads (and so copies) the HBM word
+    operand on every call.  ``block`` itself when ``dim`` is not a
+    ``quantum`` multiple (padding is then unavoidable)."""
+    if dim % quantum:
+        return block
+    for cand in range(block // quantum * quantum, 0, -quantum):
+        if dim % cand == 0:
+            return cand
+    return block
+
+
 def packed_block_sizes(m: int, kd: int, n: int, bits: int
                        ) -> Tuple[int, int, int]:
     """(bm, bn, bk) for the packed kernel at this shape.
@@ -103,8 +167,10 @@ def packed_block_sizes(m: int, kd: int, n: int, bits: int
     Priority: ``REPRO_PACKED_BLOCKS=bm,bn,bk`` env override → exact
     (M, Kd, N, bits) table hit → roofline heuristic.  The result always
     has bk a multiple of lanes (= 32 // bits) so word tiles never
-    straddle a k-block boundary.
+    straddle a k-block boundary; heuristic picks also divide N and Kd
+    where the (8, 128) tiling allows, so no call pads the word operand.
     """
+    lanes = 32 // bits
     env = os.environ.get("REPRO_PACKED_BLOCKS")
     if env:
         try:
@@ -116,19 +182,22 @@ def packed_block_sizes(m: int, kd: int, n: int, bits: int
         hit = _PACKED_BLOCK_TABLE.get((m, kd, n, bits))
         if hit is not None:
             bm, bn, bk = hit
-        elif m <= 32:
-            # Decode shape: activations fit one tile; widen the weight
-            # tiles so the DMA stream of packed words stays long.
-            bm, bn, bk = _round_up(min(m, 32), 8), 256, 1024
-        elif m <= 128:
-            bm, bn, bk = 64, 128, 512
         else:
-            # Prefill shape: MXU-balanced tiles.
-            bm, bn, bk = 128, 128, 512
-        # Don't over-pad small layers past one tile.
-        bn = min(bn, _round_up(n, 128))
-        bk = min(bk, _round_up(kd, 128))
-    lanes = 32 // bits
+            if m <= 32:
+                # Decode shape: activations fit one tile; widen the
+                # weight tiles so the DMA stream of packed words stays
+                # long.
+                bm, bn, bk = _round_up(min(m, 32), 8), 256, 1024
+            elif m <= 128:
+                bm, bn, bk = 64, 128, 512
+            else:
+                # Prefill shape: MXU-balanced tiles.
+                bm, bn, bk = 128, 128, 512
+            # Don't over-pad small layers past one tile.
+            bn = _fit_block(min(bn, _round_up(n, 128)), n, 128)
+            # a k block is 128 lanes of x and 8 sublanes of words
+            bk = _fit_block(min(bk, _round_up(kd, 128)), kd,
+                            math.lcm(128, 8 * lanes))
     bk = max(lanes, bk // lanes * lanes)
     return bm, bn, bk
 
@@ -139,12 +208,20 @@ def packed_block_sizes_t(m: int, d: int, n_out: int, bits: int, order: str
     x[M, D]·W.T).  Reuses :func:`packed_block_sizes` keyed on the
     contraction shape (M, D, V), then re-aligns the lane-packed axis:
     ``order="kd"`` packs V (the output axis) → bn must be a lanes
-    multiple; ``order="row"`` packs D (the reduction axis) → bk already
-    is.  Same ``REPRO_PACKED_BLOCKS`` override."""
+    multiple; ``order="row"`` packs D (the reduction axis) → the
+    [bn, bk/lanes] word block must be 128 words wide or the whole row
+    (Mosaic's (8, 128) block rule), and bn·bk/lanes stays ≤ 128² words:
+    the in-kernel unpack holds a [bn, bk/lanes, lanes] temporary whose
+    minor ``lanes`` axis pads to 128 lanes in VMEM.  Same
+    ``REPRO_PACKED_BLOCKS`` override."""
     bm, bn, bk = packed_block_sizes(m, d, n_out, bits)
+    lanes = 32 // bits
     if order == "kd":
-        lanes = 32 // bits
         bn = max(lanes, bn // lanes * lanes)
+    else:
+        bkw = min(-(-d // lanes), _round_up(-(-bk // lanes), 128))
+        bk = lanes * bkw
+        bn = min(bn, max(8, 128 * 128 // bkw // 8 * 8))
     return bm, bn, bk
 
 
@@ -223,7 +300,8 @@ def paged_token_tile(kind: str, feat: int, page_size: int, kv_bits: int
 def paged_attention(q: Array, k_pool: Array, v_pool: Array,
                     page_table: Array, pos: Array, alive: Array, *,
                     softcap: Optional[float] = None, scale: float,
-                    backend: Optional[str] = None) -> Array:
+                    backend: Optional[str] = None,
+                    mesh: Optional[Mesh] = None) -> Array:
     """Paged GQA decode over dense KV pages: q [B,1,H,hd] + pools
     [P+1, page, KV, hd] → [B, 1, H·hd].
 
@@ -232,6 +310,15 @@ def paged_attention(q: Array, k_pool: Array, v_pool: Array,
     it.  Pallas backends: the fused scalar-prefetch online-softmax
     kernel (``kernels.paged_attention``), allclose vs ref."""
     b = backend or default_backend()
+    m = _model_size(mesh, b)
+    if m:
+        split = q.shape[2] % m == 0 and k_pool.shape[2] % m == 0
+        h4 = _heads_spec(4, 2, split)
+        body = functools.partial(paged_attention, softcap=softcap,
+                                 scale=scale, backend=b)
+        return _shard_call(mesh, body, (h4, h4, h4, P(), P(), P()),
+                           _heads_spec(3, 2, split), q, k_pool, v_pool,
+                           page_table, pos, alive)
     if b == "ref":
         return ref.paged_attention_ref(q, k_pool, v_pool, page_table, pos,
                                        alive, softcap=softcap, scale=scale)
@@ -248,11 +335,24 @@ def paged_attention_quant(q: Array, k_words: Array, v_words: Array,
                           pos: Array, alive: Array, *, bits: int,
                           head_dim: int, softcap: Optional[float] = None,
                           scale: float,
-                          backend: Optional[str] = None) -> Array:
+                          backend: Optional[str] = None,
+                          mesh: Optional[Mesh] = None) -> Array:
     """Paged GQA decode over codebook-quantized KV pages (kv_bits/8 B per
     cached scalar): words [P+1, page, KV, Wd] uint32 + per-page codebooks
     [P+1, Gcb, K] → [B, 1, H·hd]."""
     b = backend or default_backend()
+    m = _model_size(mesh, b)
+    if m:
+        kv = k_words.shape[2]
+        split = q.shape[2] % m == 0 and kv % m == 0
+        h4 = _heads_spec(4, 2, split)
+        cb = _heads_spec(3, 1, split and k_cb.shape[1] == kv)
+        body = functools.partial(paged_attention_quant, bits=bits,
+                                 head_dim=head_dim, softcap=softcap,
+                                 scale=scale, backend=b)
+        return _shard_call(mesh, body, (h4, h4, h4, cb, cb, P(), P(), P()),
+                           _heads_spec(3, 2, split), q, k_words, v_words,
+                           k_cb, v_cb, page_table, pos, alive)
     if b == "ref":
         return ref.paged_attention_quant_ref(
             q, k_words, v_words, k_cb, v_cb, page_table, pos, alive,
@@ -262,17 +362,26 @@ def paged_attention_quant(q: Array, k_words: Array, v_words: Array,
     out = ops.paged_attention_quant(
         q, k_words, v_words, k_cb, v_cb, page_table, pos, alive, bits=bits,
         head_dim=head_dim, softcap=softcap, scale=scale, token_tile=tile,
-        dequant=default_dequant(), interpret=(b == "pallas_interpret"))
+        interpret=(b == "pallas_interpret"))
     return out.astype(k_cb.dtype)
 
 
 def mla_paged_attention(q_eff: Array, q_rope: Array, c_pool: Array,
                         r_pool: Array, page_table: Array, pos: Array,
                         alive: Array, *, scale: float,
-                        backend: Optional[str] = None) -> Array:
+                        backend: Optional[str] = None,
+                        mesh: Optional[Mesh] = None) -> Array:
     """Absorbed-MLA paged decode over dense latent pages → latent context
-    [B, 1, H, kv_lora]."""
+    [B, 1, H, kv_lora].  On a mesh the heads split; the latent pages
+    (shared by every head) replicate."""
     b = backend or default_backend()
+    m = _model_size(mesh, b)
+    if m:
+        h4 = _heads_spec(4, 2, q_eff.shape[2] % m == 0)
+        body = functools.partial(mla_paged_attention, scale=scale,
+                                 backend=b)
+        return _shard_call(mesh, body, (h4, h4) + (P(),) * 5, h4, q_eff,
+                           q_rope, c_pool, r_pool, page_table, pos, alive)
     if b == "ref":
         return ref.mla_paged_attention_ref(q_eff, q_rope, c_pool, r_pool,
                                            page_table, pos, alive,
@@ -291,9 +400,20 @@ def mla_paged_attention_quant(q_eff: Array, q_rope: Array, c_words: Array,
                               page_table: Array, pos: Array, alive: Array,
                               *, bits: int, kv_lora: int, rope_dim: int,
                               scale: float,
-                              backend: Optional[str] = None) -> Array:
-    """Absorbed-MLA paged decode over quantized latent pages."""
+                              backend: Optional[str] = None,
+                              mesh: Optional[Mesh] = None) -> Array:
+    """Absorbed-MLA paged decode over quantized latent pages (heads split
+    on a mesh, as :func:`mla_paged_attention`)."""
     b = backend or default_backend()
+    m = _model_size(mesh, b)
+    if m:
+        h4 = _heads_spec(4, 2, q_eff.shape[2] % m == 0)
+        body = functools.partial(mla_paged_attention_quant, bits=bits,
+                                 kv_lora=kv_lora, rope_dim=rope_dim,
+                                 scale=scale, backend=b)
+        return _shard_call(mesh, body, (h4, h4) + (P(),) * 7, h4, q_eff,
+                           q_rope, c_words, r_words, c_cb, r_cb, page_table,
+                           pos, alive)
     if b == "ref":
         return ref.mla_paged_attention_quant_ref(
             q_eff, q_rope, c_words, r_words, c_cb, r_cb, page_table, pos,
@@ -304,17 +424,25 @@ def mla_paged_attention_quant(q_eff: Array, q_rope: Array, c_words: Array,
     out = ops.mla_paged_attention_quant(
         q_eff, q_rope, c_words, r_words, c_cb, r_cb, page_table, pos,
         alive, bits=bits, kv_lora=kv_lora, rope_dim=rope_dim, scale=scale,
-        token_tile=tile, dequant=default_dequant(),
-        interpret=(b == "pallas_interpret"))
+        token_tile=tile, interpret=(b == "pallas_interpret"))
     return out.astype(c_cb.dtype)
 
 
 def page_gather(pool: Array, page_table: Array, alive: Array, *,
-                backend: Optional[str] = None) -> Array:
+                backend: Optional[str] = None,
+                mesh: Optional[Mesh] = None) -> Array:
     """Per-slot logical KV view [B, max_pages·page, ...] with dead slots
     masked to the trash page — the standalone gather (the fused decode
-    kernels above subsume it on the hot path)."""
+    kernels above subsume it on the hot path).  On a mesh a KV pool
+    [P+1, page, KV, ·] splits its kv heads."""
     b = backend or default_backend()
+    m = _model_size(mesh, b)
+    if m:
+        spec = _heads_spec(pool.ndim, 2,
+                           pool.ndim == 4 and pool.shape[2] % m == 0)
+        body = functools.partial(page_gather, backend=b)
+        return _shard_call(mesh, body, (spec, P(), P()), spec, pool,
+                           page_table, alive)
     if b == "ref":
         return ref.gather_pages_ref(pool, page_table, alive)
     return ops.page_gather(pool, page_table, alive,
@@ -383,7 +511,8 @@ def blockwise_prefill_attention(q: Array, k: Array, v: Array, q_pos: Array,
                                 window: Optional[int] = None,
                                 softcap: Optional[float] = None,
                                 scale: float,
-                                backend: Optional[str] = None) -> Array:
+                                backend: Optional[str] = None,
+                                mesh: Optional[Mesh] = None) -> Array:
     """Chunked-prompt prefill attention: q [B,C,H,hd] (the C new tokens)
     vs. a stored K/V view k [B,S,KV,hd] / v [B,S,KV,vd] with 1-D int32
     positions q_pos [C] / k_pos [S] → [B,C,H,vd] in the view dtype.
@@ -394,8 +523,15 @@ def blockwise_prefill_attention(q: Array, k: Array, v: Array, q_pos: Array,
     engine's fixed-capacity page view and the oracle's growing buffer
     produce bit-identical chunks.  The view is padded to a tile multiple
     here — identically on every backend — so ref and Pallas reduce over
-    the same tile partition."""
+    the same tile partition.  On a mesh the heads split."""
     b = backend or default_backend()
+    m = _model_size(mesh, b)
+    if m:
+        h4 = _heads_spec(4, 2, q.shape[2] % m == 0 and k.shape[2] % m == 0)
+        body = functools.partial(blockwise_prefill_attention, window=window,
+                                 softcap=softcap, scale=scale, backend=b)
+        return _shard_call(mesh, body, (h4, h4, h4, P(), P()), h4, q, k, v,
+                           q_pos, k_pos)
     tile = prefill_token_tile("dense", k.shape[-1])
     s = k.shape[1]
     k_pos = k_pos.astype(jnp.int32)
@@ -425,7 +561,8 @@ def blockwise_prefill_attention_quant(q: Array, k_words: Array,
                                       window: Optional[int] = None,
                                       softcap: Optional[float] = None,
                                       scale: float,
-                                      backend: Optional[str] = None
+                                      backend: Optional[str] = None,
+                                      mesh: Optional[Mesh] = None
                                       ) -> Array:
     """Chunked-prompt prefill over the slot's codebook-quantized pages:
     word view [B, S, KV, Wd] uint32 (S = n_pages·page_size, logical row
@@ -433,8 +570,20 @@ def blockwise_prefill_attention_quant(q: Array, k_words: Array,
     head_dim] in the codebook dtype.  kv_bits/8 B per cached scalar of
     KV traffic on the Pallas backends; same position-derived masking as
     the dense route (stale rows of reused pages carry sentinel
-    positions)."""
+    positions).  On a mesh the heads split."""
     b = backend or default_backend()
+    m = _model_size(mesh, b)
+    if m:
+        kv = k_words.shape[2]
+        split = q.shape[2] % m == 0 and kv % m == 0
+        h4 = _heads_spec(4, 2, split)
+        cb = _heads_spec(4, 2, split and k_cb.shape[2] == kv)
+        body = functools.partial(
+            blockwise_prefill_attention_quant, page_size=page_size,
+            bits=bits, head_dim=head_dim, window=window, softcap=softcap,
+            scale=scale, backend=b)
+        return _shard_call(mesh, body, (h4, h4, h4, cb, cb, P(), P()), h4,
+                           q, k_words, v_words, k_cb, v_cb, q_pos, k_pos)
     tile = prefill_token_tile("quant", head_dim, page_size=page_size)
     s = k_words.shape[1]
     if s % page_size:
@@ -451,7 +600,6 @@ def blockwise_prefill_attention_quant(q: Array, k_words: Array,
             q, k_words, v_words, k_cb, v_cb, q_pos, k_pos,
             page_size=page_size, bits=bits, head_dim=head_dim,
             window=window, softcap=softcap, scale=scale, token_tile=tile,
-            dequant=default_dequant(),
             interpret=(b == "pallas_interpret"))
     return out.astype(k_cb.dtype)
 
@@ -461,14 +609,10 @@ def codebook_matmul(x: Array, idx: Array, codebook: Array, *,
                     bm: int = 128, bn: int = 128, bk: int = 512) -> Array:
     """y[M, N] = x[M, Kd] · codebook[idx[Kd, N]] on the chosen backend."""
     b = backend or default_backend()
-    dq = default_dequant()
-    if b == "pallas":
-        return ops.codebook_matmul(x, idx, codebook, bm=bm, bn=bn, bk=bk,
-                                   dequant=dq, interpret=False)
-    if b == "pallas_interpret":
-        return ops.codebook_matmul(x, idx, codebook, bm=bm, bn=bn, bk=bk,
-                                   dequant=dq, interpret=True)
-    return ref.codebook_matmul_ref(x, idx, codebook)
+    if b == "ref":
+        return ref.codebook_matmul_ref(x, idx, codebook)
+    return ops.codebook_matmul(x, idx, codebook, bm=bm, bn=bn, bk=bk,
+                               interpret=(b == "pallas_interpret"))
 
 
 def packed_codebook_matmul(x: Array, pidx: Array, codebook: Array, *,
@@ -504,12 +648,14 @@ def packed_codebook_matmul(x: Array, pidx: Array, codebook: Array, *,
         return ref.packed_codebook_matmul_ref(x, pidx, codebook)
     bm, bn, bk = blocks or packed_block_sizes(m, kd, n, bits)
     return ops.packed_codebook_matmul(
-        x, pidx, codebook, bm=bm, bn=bn, bk=bk, dequant=default_dequant(),
+        x, pidx, codebook, bm=bm, bn=bn, bk=bk,
         interpret=(b == "pallas_interpret"))
 
 
 def quantized_matmul(x: Array, idx: Array, codebook: Array, *,
-                     backend: Optional[str] = None) -> Array:
+                     backend: Optional[str] = None,
+                     mesh: Optional[Mesh] = None,
+                     split: Optional[str] = None) -> Array:
     """Batched-x wrapper: x[..., Kd] · codebook[idx[Kd, N]] → [..., N].
 
     This is the serve-path entry ``models.qleaf.qmatmul`` uses when a
@@ -517,12 +663,21 @@ def quantized_matmul(x: Array, idx: Array, codebook: Array, *,
 
     On the ``ref`` backend (the CPU serving default) the contraction is
     literally ``x @ codebook[idx]`` — the identical graph as the dense
-    layout, so packed-vs-dense serving is bit-exact there.
+    layout, so packed-vs-dense serving is bit-exact there.  ``mesh`` and
+    ``split`` map the kernel route over devices (see
+    :func:`_split_matmul`).
     """
     b = backend or default_backend()
     if b == "ref" or idx.ndim != 2:
+        if b != "ref":
+            FALLBACKS["dequant_dot"] += 1
         y = x @ decode_leaf(idx, codebook)
         return y.astype(x.dtype)
+    m = _model_size(mesh, b)
+    if m:
+        fn = functools.partial(quantized_matmul, backend=b)
+        return _split_matmul(mesh, m, split, x.shape[-1] % m == 0, fn, x,
+                             idx, codebook)
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     y = codebook_matmul(x2, idx, codebook, backend=b)
@@ -531,21 +686,36 @@ def quantized_matmul(x: Array, idx: Array, codebook: Array, *,
 
 def packed_quantized_matmul(x: Array, pidx: Array, codebook: Array, *,
                             layout: Optional[PackedLayout] = None,
-                            backend: Optional[str] = None) -> Array:
+                            backend: Optional[str] = None,
+                            mesh: Optional[Mesh] = None,
+                            split: Optional[str] = None) -> Array:
     """Batched-x wrapper over :func:`packed_codebook_matmul` — the serve-
     path entry ``models.qleaf.qmatmul`` uses for the ``<name>_pidx``
     layout.  Same bit-exact dense-graph property on ``ref`` as
     :func:`quantized_matmul`; non-matrix layouts (``layout.shape`` set)
-    always take the dequant-then-dot route."""
+    always take the dequant-then-dot route.  ``mesh`` and ``split`` map
+    the kernel route over devices (see :func:`_split_matmul`)."""
     b = backend or default_backend()
     nd = layout is not None and (layout.shape is not None
                                  or layout.order != "kd")
     if b == "ref" or pidx.ndim != 2 or nd:
+        if b != "ref":
+            FALLBACKS["dequant_dot"] += 1
         if layout is None:
             raise ValueError("packed_quantized_matmul needs the static "
                              "PackedLayout on the dequant route")
         y = x @ decode_packed_leaf(pidx, codebook, layout)
         return y.astype(x.dtype)
+    m = _model_size(mesh, b)
+    if m:
+        lanes = 32 // bits_per_index(int(codebook.shape[-1]))
+
+        def fn(x, w, c):
+            loc = (None if layout is None else dataclasses.replace(
+                layout, kd=x.shape[-1], n=w.shape[-1]))
+            return packed_quantized_matmul(x, w, c, layout=loc, backend=b)
+        return _split_matmul(mesh, m, split, x.shape[-1] % (m * lanes) == 0,
+                             fn, x, pidx, codebook)
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     y = packed_codebook_matmul(x2, pidx, codebook, layout=layout,
@@ -557,7 +727,7 @@ def packed_quantized_matmul_t(x: Array, pidx: Array, codebook: Array, *,
                               layout: PackedLayout,
                               backend: Optional[str] = None,
                               blocks: Optional[Tuple[int, int, int]] = None,
-                              ) -> Array:
+                              mesh: Optional[Mesh] = None) -> Array:
     """y[..., V] = x[..., D] · codebook[unpack(pidx)].T — the fused
     transposed (tied/untied LM-head) route over a packed [V, D] leaf.
 
@@ -567,14 +737,31 @@ def packed_quantized_matmul_t(x: Array, pidx: Array, codebook: Array, *,
     ``bits_per_index(K)/8`` bytes/weight, no dense [V, D] temporary.  On
     the ``ref`` backend (CPU serving default) the contraction is literally
     ``x @ decode.T`` — the identical graph as the dense layout, so
-    packed-vs-dense logits are bit-exact there.
+    packed-vs-dense logits are bit-exact there.  On a mesh the vocabulary
+    splits over ``model`` (the embedding table's placement), and so do
+    the logits.
     """
     b = backend or default_backend()
     if b == "ref" or pidx.ndim != 2 or layout.shape is not None \
             or codebook.ndim != 1:
+        if b != "ref":
+            FALLBACKS["dequant_dot"] += 1
         w = decode_packed_leaf(pidx, codebook, layout)
         y = x @ jnp.matrix_transpose(w)
         return y.astype(x.dtype)
+    m = _model_size(mesh, b)
+    if m:
+        rows = pidx.shape[0]        # V ("row") or ⌈V/lanes⌉ ("kd") words
+        split = m > 1 and rows % m == 0 and (
+            layout.order == "row" or layout.kd == rows * layout.lanes)
+        loc = (dataclasses.replace(layout, kd=layout.kd // m) if split
+               else layout)
+        fn = functools.partial(packed_quantized_matmul_t, layout=loc,
+                               backend=b, blocks=blocks)
+        vocab = P(*(None,) * (x.ndim - 1), "model") if split else P()
+        return _shard_call(mesh, fn,
+                           (P(), P("model", None) if split else P(), P()),
+                           vocab, x, pidx, codebook)
     if pidx.shape != layout.word_shape:
         raise ValueError(f"pidx {pidx.shape} != layout word shape "
                          f"{layout.word_shape} ({layout})")
@@ -584,14 +771,15 @@ def packed_quantized_matmul_t(x: Array, pidx: Array, codebook: Array, *,
         x2.shape[0], layout.n, layout.kd, layout.bits, layout.order)
     y = ops.packed_codebook_matmul_t(
         x2, pidx, codebook, layout.kd, order=layout.order, bm=bm, bn=bn,
-        bk=bk, dequant=default_dequant(),
+        bk=bk,
         interpret=(b == "pallas_interpret"))
     return y.reshape(lead + (layout.kd,)).astype(x.dtype)
 
 
 def quantized_gather(tokens: Array, pidx: Array, codebook: Array, *,
                      layout: PackedLayout,
-                     backend: Optional[str] = None) -> Array:
+                     backend: Optional[str] = None,
+                     mesh: Optional[Mesh] = None) -> Array:
     """Embedding dequant-on-gather: rows ``codebook[unpack(pidx)[tokens]]``
     without ever materializing the dense [V, D] table.
 
@@ -599,33 +787,61 @@ def quantized_gather(tokens: Array, pidx: Array, codebook: Array, *,
     :func:`~repro.core.compression.pack_rows`, [V, ⌈D/lanes⌉] uint32): a
     token's lookup reads its contiguous packed word row — exactly
     ``bits_per_index(K)/8`` bytes per gathered weight.  On TPU this is the
-    Mosaic kernel (``kernels.quantized_gather``: scalar-prefetch row DMA →
-    shift+mask → K-entry LUT); the jnp route (word-row gather + unpack,
-    the same bytes) is the CPU reference — a pure gather, so it is
-    bit-exact vs the dense table on every backend.
+    Mosaic kernel (``kernels.quantized_gather``: one row DMA per token →
+    shift+mask → codebook select) when a word row is ``ROW_WORDS`` wide,
+    the only width Mosaic DMAs row by row (the refusals at the other
+    widths are listed beside ``ROW_WORDS``); the jnp route (XLA word-row
+    gather + unpack, the same bytes) serves other widths and is the CPU
+    reference — a pure gather, so it is bit-exact vs the dense table on
+    every backend.
 
     ``layout.order == "kd"`` (the pre-row-pack column layout,
     :func:`~repro.core.compression.pack_indices_2d` over the vocab axis):
     retained jnp fallback — gathers one full uint32 word per embedding
     *column* (4 B/weight), shift+masks the token's lane.  A 2-D codebook
     is per-group ([G, K]) — not needed for the root embedding table.
+
+    On a mesh the kernel route splits the vocabulary over ``model`` (the
+    table's placement): each device looks up the tokens in its own rows,
+    zeroes the rest, and a psum assembles the rows exactly.
     """
     tokens = tokens.astype(jnp.int32)
-    if layout.order == "row":
-        b = backend or default_backend()
-        if b != "ref" and pidx.ndim == 2 and codebook.ndim == 1:
-            lead = tokens.shape
-            out = ops.quantized_gather(
-                tokens.reshape(-1), pidx, codebook, layout.n,
-                dequant=default_dequant(),
-                interpret=(b == "pallas_interpret"))
-            rows = out.reshape(lead + (layout.n,))
-        else:
-            words = pidx[tokens]                     # [..., ⌈D/lanes⌉]
-            idx = unpack_rows(words, layout.n, layout.k)
-            rows = codebook[idx]
+    b = backend or default_backend()
+    m = _model_size(mesh, b)
+    if m and layout.order == "row" and pidx.ndim == 2 \
+            and codebook.ndim == 1:
+        if m > 1 and layout.kd % m == 0:
+            loc = dataclasses.replace(layout, kd=layout.kd // m)
+
+            def fn(t, w, c):
+                t = t - jax.lax.axis_index("model") * loc.kd
+                hit = (t >= 0) & (t < loc.kd)
+                rows = quantized_gather(jnp.where(hit, t, 0), w, c,
+                                        layout=loc, backend=b)
+                return jax.lax.psum(
+                    jnp.where(hit[..., None], rows, 0), "model")
+            return _shard_call(mesh, fn, (P(), P("model", None), P()), P(),
+                               tokens, pidx, codebook)
+        fn = functools.partial(quantized_gather, layout=layout, backend=b)
+        return _shard_call(mesh, fn, (P(), P(), P()), P(), tokens, pidx,
+                           codebook)
+    kernel = b == "pallas_interpret" or (b == "pallas"
+                                         and pidx.shape[-1] == ROW_WORDS)
+    if layout.order == "row" and kernel and pidx.ndim == 2 \
+            and codebook.ndim == 1:
+        lead = tokens.shape
+        out = ops.quantized_gather(tokens.reshape(-1), pidx, codebook,
+                                   layout.n, interpret=(b != "pallas"))
+        rows = out.reshape(lead + (layout.n,))
+    elif layout.order == "row":
+        if b != "ref":
+            FALLBACKS["row_gather"] += 1
+        words = pidx[tokens]                         # [..., ⌈D/lanes⌉]
+        idx = unpack_rows(words, layout.n, layout.k)
+        rows = codebook[idx]
     else:
-        del backend              # single (jnp reference) backend for "kd"
+        if b != "ref":
+            FALLBACKS["row_gather"] += 1
         mask = jnp.uint32((1 << layout.bits) - 1)
         words = pidx[tokens // layout.lanes]         # [..., D] uint32
         lane = (tokens % layout.lanes).astype(jnp.uint32)

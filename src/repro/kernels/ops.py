@@ -6,6 +6,7 @@ call sites compile to Mosaic.  ``interpret=None`` → auto-detect backend.
 """
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Optional, Tuple
 
@@ -27,9 +28,15 @@ from repro.kernels.paged_attention import (
 from repro.kernels.quantized_gather import quantized_gather_pallas
 
 
+# Kernel calls by mode, tallied when each call is traced: a chip run
+# reads it to prove that no kernel ran in the Pallas interpreter.
+CALLS: collections.Counter = collections.Counter()
+
+
 def _auto_interpret(interpret: Optional[bool]) -> bool:
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        interpret = jax.default_backend() != "tpu"
+    CALLS["interpret" if interpret else "mosaic"] += 1
     return interpret
 
 
@@ -46,51 +53,45 @@ def kmeans_assign(w: jax.Array, codebook: jax.Array,
                               _auto_interpret(interpret))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "dequant", "interpret"))
-def _codebook_matmul_jit(x, idx, codebook, bm, bn, bk, dequant, interpret):
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def _codebook_matmul_jit(x, idx, codebook, bm, bn, bk, interpret):
     return codebook_matmul_pallas(x, idx, codebook, bm=bm, bn=bn, bk=bk,
-                                  dequant=dequant, interpret=interpret)
+                                  interpret=interpret)
 
 
 def codebook_matmul(x: jax.Array, idx: jax.Array, codebook: jax.Array,
                     *, bm: int = 128, bn: int = 128, bk: int = 512,
-                    dequant: str = "lut",
                     interpret: Optional[bool] = None) -> jax.Array:
     """y = x · codebook[idx] without materializing float weights in HBM."""
-    return _codebook_matmul_jit(x, idx, codebook, bm, bn, bk, dequant,
+    return _codebook_matmul_jit(x, idx, codebook, bm, bn, bk,
                                 _auto_interpret(interpret))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "dequant", "interpret"))
-def _packed_codebook_matmul_jit(x, pidx, codebook, bm, bn, bk, dequant,
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def _packed_codebook_matmul_jit(x, pidx, codebook, bm, bn, bk,
                                 interpret):
     return codebook_matmul_packed_pallas(x, pidx, codebook, bm=bm, bn=bn,
-                                         bk=bk, dequant=dequant,
-                                         interpret=interpret)
+                                         bk=bk, interpret=interpret)
 
 
 def packed_codebook_matmul(x: jax.Array, pidx: jax.Array,
                            codebook: jax.Array, *, bm: int = 128,
                            bn: int = 128, bk: int = 512,
-                           dequant: str = "lut",
                            interpret: Optional[bool] = None) -> jax.Array:
     """y = x · codebook[unpack(pidx)] with the ``pack_indices_2d`` uint32
     word operand HBM-resident: bits_per_index(K)/8 bytes/weight of index
     traffic (see codebook_matmul_packed.py)."""
     return _packed_codebook_matmul_jit(x, pidx, codebook, bm, bn, bk,
-                                       dequant, _auto_interpret(interpret))
+                                       _auto_interpret(interpret))
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_out", "order", "bm", "bn", "bk",
-                                    "dequant", "interpret"))
+                                    "interpret"))
 def _packed_codebook_matmul_t_jit(x, pidx, codebook, n_out, order, bm, bn,
-                                  bk, dequant, interpret):
+                                  bk, interpret):
     return codebook_matmul_packed_t_pallas(x, pidx, codebook, n_out,
                                            order=order, bm=bm, bn=bn, bk=bk,
-                                           dequant=dequant,
                                            interpret=interpret)
 
 
@@ -98,31 +99,28 @@ def packed_codebook_matmul_t(x: jax.Array, pidx: jax.Array,
                              codebook: jax.Array, n_out: int, *,
                              order: str = "kd", bm: int = 128,
                              bn: int = 128, bk: int = 512,
-                             dequant: str = "lut",
                              interpret: Optional[bool] = None) -> jax.Array:
     """y = x · codebook[unpack(pidx)].T — the fused transposed (LM-head)
     route; the packed word operand stays HBM-resident (see
     codebook_matmul_packed_t.py)."""
     return _packed_codebook_matmul_t_jit(x, pidx, codebook, n_out, order,
-                                         bm, bn, bk, dequant,
+                                         bm, bn, bk,
                                          _auto_interpret(interpret))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("d", "dequant", "interpret"))
-def _quantized_gather_jit(tokens, pidx, codebook, d, dequant, interpret):
+@functools.partial(jax.jit, static_argnames=("d", "interpret"))
+def _quantized_gather_jit(tokens, pidx, codebook, d, interpret):
     return quantized_gather_pallas(tokens, pidx, codebook, d,
-                                   dequant=dequant, interpret=interpret)
+                                   interpret=interpret)
 
 
 def quantized_gather(tokens: jax.Array, pidx: jax.Array,
                      codebook: jax.Array, d: int, *,
-                     dequant: str = "lut",
                      interpret: Optional[bool] = None) -> jax.Array:
     """rows = codebook[unpack(pidx[tokens])] — Mosaic dequant-on-gather
     over the pack_rows embedding layout: ``bits_per_index(K)/8`` HBM bytes
     per gathered weight (see quantized_gather.py)."""
-    return _quantized_gather_jit(tokens, pidx, codebook, d, dequant,
+    return _quantized_gather_jit(tokens, pidx, codebook, d,
                                  _auto_interpret(interpret))
 
 
@@ -147,25 +145,25 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, alive, *,
 
 @functools.partial(jax.jit,
                    static_argnames=("bits", "head_dim", "softcap", "scale",
-                                    "token_tile", "dequant", "interpret"))
+                                    "token_tile", "interpret"))
 def _paged_attention_quant_jit(q, k_words, v_words, k_cb, v_cb, page_table,
                                pos, alive, bits, head_dim, softcap, scale,
-                               token_tile, dequant, interpret):
+                               token_tile, interpret):
     return paged_attention_quant_pallas(
         q, k_words, v_words, k_cb, v_cb, page_table, pos, alive, bits=bits,
         head_dim=head_dim, softcap=softcap, scale=scale,
-        token_tile=token_tile, dequant=dequant, interpret=interpret)
+        token_tile=token_tile, interpret=interpret)
 
 
 def paged_attention_quant(q, k_words, v_words, k_cb, v_cb, page_table, pos,
                           alive, *, bits, head_dim, softcap=None, scale,
-                          token_tile=None, dequant: str = "lut",
+                          token_tile=None,
                           interpret: Optional[bool] = None) -> jax.Array:
     """GQA decode over codebook-quantized KV pages: kv_bits/8 B per cached
     scalar of HBM traffic, dequant in VMEM (paged_attention.py)."""
     return _paged_attention_quant_jit(q, k_words, v_words, k_cb, v_cb,
                                       page_table, pos, alive, bits, head_dim,
-                                      softcap, scale, token_tile, dequant,
+                                      softcap, scale, token_tile,
                                       _auto_interpret(interpret))
 
 
@@ -190,32 +188,31 @@ def mla_paged_attention(q_eff, q_rope, c_pool, r_pool, page_table, pos,
 
 @functools.partial(jax.jit,
                    static_argnames=("bits", "kv_lora", "rope_dim", "scale",
-                                    "token_tile", "dequant", "interpret"))
+                                    "token_tile", "interpret"))
 def _mla_paged_attention_quant_jit(q_eff, q_rope, c_words, r_words, c_cb,
                                    r_cb, page_table, pos, alive, bits,
                                    kv_lora, rope_dim, scale, token_tile,
-                                   dequant, interpret):
+                                   interpret):
     return mla_paged_attention_quant_pallas(
         q_eff, q_rope, c_words, r_words, c_cb, r_cb, page_table, pos, alive,
         bits=bits, kv_lora=kv_lora, rope_dim=rope_dim, scale=scale,
-        token_tile=token_tile, dequant=dequant, interpret=interpret)
+        token_tile=token_tile, interpret=interpret)
 
 
 def mla_paged_attention_quant(q_eff, q_rope, c_words, r_words, c_cb, r_cb,
                               page_table, pos, alive, *, bits, kv_lora,
                               rope_dim, scale, token_tile=None,
-                              dequant: str = "lut",
                               interpret: Optional[bool] = None) -> jax.Array:
     """Absorbed-MLA decode over codebook-quantized latent pages."""
     return _mla_paged_attention_quant_jit(
         q_eff, q_rope, c_words, r_words, c_cb, r_cb, page_table, pos, alive,
         bits, kv_lora, rope_dim, scale, token_tile,
-        dequant, _auto_interpret(interpret))
+        _auto_interpret(interpret))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("window", "softcap", "scale",
-                                    "token_tile", "interpret"))
+                   static_argnames=("window", "softcap", "scale", "token_tile",
+                                    "interpret"))
 def _blockwise_prefill_jit(q, k, v, q_pos, k_pos, window, softcap, scale,
                            token_tile, interpret):
     return blockwise_prefill_pallas(q, k, v, q_pos, k_pos, window=window,
@@ -235,30 +232,29 @@ def blockwise_prefill(q, k, v, q_pos, k_pos, *, window=None, softcap=None,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("page_size", "bits", "head_dim",
-                                    "window", "softcap", "scale",
-                                    "token_tile", "dequant", "interpret"))
+                   static_argnames=("page_size", "bits", "head_dim", "window",
+                                    "softcap", "scale", "token_tile",
+                                    "interpret"))
 def _blockwise_prefill_quant_jit(q, k_words, v_words, k_cb, v_cb, q_pos,
                                  k_pos, page_size, bits, head_dim, window,
-                                 softcap, scale, token_tile, dequant,
+                                 softcap, scale, token_tile,
                                  interpret):
     return blockwise_prefill_quant_pallas(
         q, k_words, v_words, k_cb, v_cb, q_pos, k_pos, page_size=page_size,
         bits=bits, head_dim=head_dim, window=window, softcap=softcap,
-        scale=scale, token_tile=token_tile, dequant=dequant,
+        scale=scale, token_tile=token_tile,
         interpret=interpret)
 
 
 def blockwise_prefill_quant(q, k_words, v_words, k_cb, v_cb, q_pos, k_pos,
                             *, page_size, bits, head_dim, window=None,
                             softcap=None, scale, token_tile,
-                            dequant: str = "lut",
                             interpret: Optional[bool] = None) -> jax.Array:
     """Chunked-prompt prefill over codebook-quantized KV pages: kv_bits/8
     B per cached scalar of HBM traffic (blockwise_prefill.py)."""
     return _blockwise_prefill_quant_jit(
         q, k_words, v_words, k_cb, v_cb, q_pos, k_pos, page_size, bits,
-        head_dim, window, softcap, scale, token_tile, dequant,
+        head_dim, window, softcap, scale, token_tile,
         _auto_interpret(interpret))
 
 

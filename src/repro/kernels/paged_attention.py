@@ -123,25 +123,23 @@ def _gqa_kernel(tbl_ref, pos_ref, alive_ref, q_ref, k_ref, v_ref, o_ref,
               valid=valid, j=j, nj=nj, softcap=softcap, scale=scale)
 
 
-def _dequant_kv_tile(words, cb, *, head_dim, bits, dequant):
+def _dequant_kv_tile(words, cb, *, head_dim, bits):
     """[bt, KV, Wd] uint32 words + [Gcb, K] codebooks → [bt, KV, hd] f32."""
     bt, kv, wd = words.shape
-    k_entries = kv_entries(bits)
     idx = unpack_words_axis1(words.reshape(bt * kv, wd), bits)
     idx = idx[:, :head_dim].reshape(bt, kv, head_dim)
+    cb = cb.astype(jnp.float32)
     if cb.shape[0] == 1:          # one codebook per page
-        vals = dequant_tile(idx.reshape(bt * kv, head_dim),
-                            cb[0].astype(jnp.float32), k_entries, dequant)
+        vals = dequant_tile(idx.reshape(bt * kv, head_dim), cb)
         return vals.reshape(bt, kv, head_dim)
-    heads = [dequant_tile(idx[:, g, :], cb[g].astype(jnp.float32),
-                          k_entries, dequant)
+    heads = [dequant_tile(idx[:, g, :], cb[g:g + 1])
              for g in range(kv)]   # per-kv-head codebooks, KV is static
     return jnp.stack(heads, axis=1)
 
 
 def _gqa_quant_kernel(tbl_ref, pos_ref, alive_ref, q_ref, kw_ref, vw_ref,
                       kcb_ref, vcb_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                      bt, nj, softcap, scale, head_dim, bits, dequant):
+                      bt, nj, softcap, scale, head_dim, bits):
     del tbl_ref
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -154,9 +152,9 @@ def _gqa_quant_kernel(tbl_ref, pos_ref, alive_ref, q_ref, kw_ref, vw_ref,
 
     valid = _tile_valid(pos_ref, alive_ref, b, j, bt)
     k = _dequant_kv_tile(kw_ref[0], kcb_ref[0], head_dim=head_dim,
-                         bits=bits, dequant=dequant)
+                         bits=bits)
     v = _dequant_kv_tile(vw_ref[0], vcb_ref[0], head_dim=head_dim,
-                         bits=bits, dequant=dequant)
+                         bits=bits)
     _gqa_body(q_ref, k, v, o_ref, m_ref, l_ref, acc_ref, valid=valid,
               j=j, nj=nj, softcap=softcap, scale=scale)
 
@@ -216,7 +214,7 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, alive, *,
 def paged_attention_quant_pallas(q, k_words, v_words, k_cb, v_cb,
                                  page_table, pos, alive, *, bits, head_dim,
                                  softcap=None, scale, token_tile=None,
-                                 dequant="lut", interpret=False):
+                                 interpret=False):
     """Quantized-KV paged GQA decode: words [P+1, page, KV, Wd] uint32,
     per-page codebooks [P+1, Gcb, K] → [B, 1, H·hd] f32."""
     b, _, h, hd = q.shape
@@ -258,8 +256,7 @@ def paged_attention_quant_pallas(q, k_words, v_words, k_cb, v_cb,
     )
     out = pl.pallas_call(
         functools.partial(_gqa_quant_kernel, bt=bt, nj=nj, softcap=softcap,
-                          scale=scale, head_dim=head_dim, bits=bits,
-                          dequant=dequant),
+                          scale=scale, head_dim=head_dim, bits=bits),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
         interpret=interpret,
@@ -320,17 +317,15 @@ def _mla_kernel(tbl_ref, pos_ref, alive_ref, qe_ref, qr_ref, c_ref, r_ref,
               valid=valid, j=j, nj=nj, scale=scale)
 
 
-def _dequant_lat_tile(words, cb, *, d, bits, dequant):
+def _dequant_lat_tile(words, cb, *, d, bits):
     """[bt, Wd] uint32 + [1, K] codebook → [bt, d] f32."""
     idx = unpack_words_axis1(words, bits)[:, :d]
-    return dequant_tile(idx, cb[0].astype(jnp.float32), kv_entries(bits),
-                        dequant)
+    return dequant_tile(idx, cb.astype(jnp.float32))
 
 
 def _mla_quant_kernel(tbl_ref, pos_ref, alive_ref, qe_ref, qr_ref, cw_ref,
                       rw_ref, ccb_ref, rcb_ref, o_ref, m_ref, l_ref,
-                      acc_ref, *, bt, nj, scale, kv_lora, rope_dim, bits,
-                      dequant):
+                      acc_ref, *, bt, nj, scale, kv_lora, rope_dim, bits):
     del tbl_ref
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -342,10 +337,8 @@ def _mla_quant_kernel(tbl_ref, pos_ref, alive_ref, qe_ref, qr_ref, cw_ref,
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     valid = _tile_valid(pos_ref, alive_ref, b, j, bt)
-    ckv = _dequant_lat_tile(cw_ref[0], ccb_ref[0], d=kv_lora, bits=bits,
-                            dequant=dequant)
-    kr = _dequant_lat_tile(rw_ref[0], rcb_ref[0], d=rope_dim, bits=bits,
-                           dequant=dequant)
+    ckv = _dequant_lat_tile(cw_ref[0], ccb_ref[0], d=kv_lora, bits=bits)
+    kr = _dequant_lat_tile(rw_ref[0], rcb_ref[0], d=rope_dim, bits=bits)
     _mla_body(qe_ref, qr_ref, ckv, kr, o_ref, m_ref, l_ref, acc_ref,
               valid=valid, j=j, nj=nj, scale=scale)
 
@@ -401,8 +394,7 @@ def mla_paged_attention_pallas(q_eff, q_rope, c_pool, r_pool, page_table,
 def mla_paged_attention_quant_pallas(q_eff, q_rope, c_words, r_words, c_cb,
                                      r_cb, page_table, pos, alive, *, bits,
                                      kv_lora, rope_dim, scale,
-                                     token_tile=None, dequant="lut",
-                                     interpret=False):
+                                     token_tile=None, interpret=False):
     """Quantized latent pages: words [P+1, page, W*] uint32 + per-page
     codebooks [P+1, 1, K] → latent context [B, 1, H, L] f32."""
     b, _, h, lat = q_eff.shape
@@ -448,8 +440,7 @@ def mla_paged_attention_quant_pallas(q_eff, q_rope, c_words, r_words, c_cb,
     )
     out = pl.pallas_call(
         functools.partial(_mla_quant_kernel, bt=bt, nj=nj, scale=scale,
-                          kv_lora=kv_lora, rope_dim=rope_dim, bits=bits,
-                          dequant=dequant),
+                          kv_lora=kv_lora, rope_dim=rope_dim, bits=bits),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, lat), jnp.float32),
         interpret=interpret,

@@ -8,9 +8,10 @@ VPU-friendly steps on a uint32 word tile that just DMA'd into VMEM:
    little-endian indices at a fixed ``bits = bits_per_index(K)`` width
    (no straddling); a broadcasted-iota shift plus an AND expands the word
    tile to an index tile;
-2. **dequant** — a K-entry LUT gather ``cb[idx]`` (O(1) in K), or the
-   MXU-shaped one-hot contraction fallback for Mosaic versions that lower
-   small-table gathers poorly (``REPRO_DEQUANT=onehot``).
+2. **dequant** — a K-entry codebook lookup written as a compare+select
+   chain over the entries (Mosaic lowers only 2-D gathers, so
+   ``jnp.take(cb, idx)`` is refused on a TPU; the chain is plain VPU
+   work and keeps one full tile live).
 
 Two unpack orientations cover every packed operand layout:
 
@@ -64,16 +65,14 @@ def unpack_words_axis1(words: Array, bits: int) -> Array:
     return idx.reshape(r, w * lanes).astype(jnp.int32)
 
 
-def dequant_tile(idx: Array, cb: Array, k_entries: int, dequant: str) -> Array:
-    """[R, C] int32 indices + [K] codebook → [R, C] float weights.
+def dequant_tile(idx: Array, cb: Array) -> Array:
+    """[R, C] int32 indices + [1, K] codebook row → [R, C] float weights.
 
-    ``dequant="lut"``: K-entry gather, O(R·C) independent of K.
-    ``dequant="onehot"``: one-hot contraction, O(R·C·K) but MXU-shaped —
-    the fallback for Mosaic versions that lower small gathers poorly.
+    ``out = cb[idx]`` as a compare+select chain over the K entries: O(K)
+    VPU ops per weight (K ≤ 16 on the served routes), no gather and no
+    [R, C, K] temporary, so it lowers on Mosaic at any tile shape.
     """
-    if dequant == "lut":
-        return jnp.take(cb, idx, axis=0)
-    r, c = idx.shape
-    onehot = (idx[:, :, None] ==
-              jax.lax.broadcasted_iota(jnp.int32, (r, c, k_entries), 2))
-    return jnp.sum(onehot.astype(cb.dtype) * cb[None, None, :], axis=2)
+    out = jnp.broadcast_to(cb[:, 0:1], idx.shape)
+    for k in range(1, cb.shape[-1]):
+        out = jnp.where(idx == k, cb[:, k:k + 1], out)
+    return out

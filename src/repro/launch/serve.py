@@ -49,7 +49,9 @@ import numpy as np                            # noqa: E402
 from repro.configs import get_config, list_archs, reduce_config  # noqa: E402
 from repro.dist import sharding as shard_rules                   # noqa: E402
 from repro.engine import Engine, Request, greedy_generate        # noqa: E402
-from repro.launch.mesh import make_production_mesh               # noqa: E402
+from repro.engine.outcomes import Outcome                         # noqa: E402
+from repro.launch.compile_cache import setup_compile_cache        # noqa: E402
+from repro.launch.mesh import mesh_from_spec                      # noqa: E402
 from repro.models import sharding_ctx                            # noqa: E402
 from repro.models.transformer import init_params                 # noqa: E402
 from repro.train import checkpoint as ckpt                       # noqa: E402
@@ -97,7 +99,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b", choices=list_archs())
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--mesh", default=None)
+    ap.add_argument("--mesh", default=None,
+                    help="e.g. 2x2 (data x model); default: (1, n) over "
+                         "the devices that exist")
     ap.add_argument("--host-devices", type=int, default=None)
     ap.add_argument("--batch", type=int, default=4,
                     help="one-shot batch size / engine slot count alias")
@@ -155,15 +159,11 @@ def main():
                     help="steps between snapshots (with --snapshot-dir)")
     args = ap.parse_args()
 
+    setup_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_config(cfg)
-    if args.mesh:
-        dims = tuple(int(x) for x in args.mesh.split("x"))
-        names = ("pod", "data", "model")[-len(dims):]
-        mesh = jax.make_mesh(dims, names)
-    else:
-        mesh = make_production_mesh()
+    mesh = mesh_from_spec(args.mesh)
     sharding_ctx.set_policy(sharding_ctx.Policy(mesh, mode="tp"))
 
     params = _load_params(args, cfg)
@@ -229,6 +229,8 @@ def main():
     n_bad = sum(not res.ok for res in results.values())
     if n_bad:
         print(f"outcomes: {len(results) - n_bad}/{len(results)} finished")
+    n_failed = sum(res.outcome is Outcome.FAILED
+                   for res in results.values())
     if eng is not None:
         s = eng.stats.summary()
         print(f"engine: {s['delivered_tokens']} tokens in {s['steps']} "
@@ -238,6 +240,11 @@ def main():
               f" peak {s['page_utilization_max']:.2f}, "
               f"{s['preemptions']} preemptions, decode compiled "
               f"{eng.decode_compile_count()}x)")
+    if n_failed:
+        # rejections and deadlines are outcomes the user configured; a
+        # FAILED request (non-finite logits, exhausted preemption budget,
+        # max_steps overrun) is the server failing
+        sys.exit(f"{n_failed} request(s) FAILED")
 
 
 if __name__ == "__main__":

@@ -38,27 +38,24 @@ from repro.configs import get_config, list_archs, reduce_config  # noqa: E402
 from repro.core import CompressionPlan, LCConfig                 # noqa: E402
 from repro.data.pipeline import LMTokenPipeline, shard_batch     # noqa: E402
 from repro.dist import sharding as shard_rules                   # noqa: E402
-from repro.launch.mesh import make_production_mesh               # noqa: E402
+from repro.launch.compile_cache import setup_compile_cache        # noqa: E402
+from repro.launch.mesh import make_production_mesh, mesh_from_spec  # noqa: E402
 from repro.models import sharding_ctx                            # noqa: E402
 from repro.models.transformer import init_params, loss_fn        # noqa: E402
 from repro.train import checkpoint as ckpt                       # noqa: E402
 from repro.train.trainer import (LCTrainer, TrainerConfig)       # noqa: E402
 
 
-def parse_mesh(spec: str):
-    dims = tuple(int(x) for x in spec.split("x"))
-    if len(dims) == 3:
-        return jax.make_mesh(dims, ("pod", "data", "model"))
-    return jax.make_mesh(dims, ("data", "model"))
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b", choices=list_archs())
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--mesh", default=None, help="e.g. 2x2 or 2x2x2")
+    ap.add_argument("--mesh", default=None,
+                    help="e.g. 2x2 or 2x2x2; default: (1, n) over the "
+                         "devices that exist")
     ap.add_argument("--host-devices", type=int, default=None)
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--multi-pod", action="store_true",
+                    help="the 2x16x16 production mesh")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--steps", type=int, default=40)
@@ -71,11 +68,12 @@ def main():
     ap.add_argument("--zero", action="store_true")
     args = ap.parse_args()
 
+    setup_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_config(cfg)
-    mesh = (parse_mesh(args.mesh) if args.mesh
-            else make_production_mesh(multi_pod=args.multi_pod))
+    mesh = (make_production_mesh(multi_pod=True) if args.multi_pod
+            else mesh_from_spec(args.mesh))
     print(f"mesh: {dict(mesh.shape)}; model: {cfg.name}")
 
     sharding_ctx.set_policy(sharding_ctx.Policy(mesh, mode="tp"))

@@ -28,7 +28,7 @@ from repro.core import kvquant
 from repro.kernels import dispatch
 from repro.models.layers import apply_rope, softcap
 from repro.models.qleaf import qmatmul, qweight
-from repro.models.sharding_ctx import constrain
+from repro.models.sharding_ctx import active_mesh, constrain
 
 Array = jax.Array
 NEG_INF = -1e30
@@ -453,7 +453,8 @@ def _gather_slots(pool: Array, page_table: Array, alive: Array) -> Array:
     contributes one repeated trash page instead of ``max_pages``
     arbitrary live pages to the gather footprint.
     """
-    return dispatch.page_gather(pool, page_table, alive)
+    return dispatch.page_gather(pool, page_table, alive,
+                                mesh=active_mesh())
 
 
 def _slot_attention(q, ck, cv, valid, *, n_heads, n_kv, head_dim,
@@ -494,7 +495,8 @@ def gqa_decode_paged(p, x_t, cache: PagedKVCache, page_table, pos, alive, *,
     # fused page-gather + online-softmax decode; the CPU ref route is the
     # verbatim former _gather_slots/_slot_attention math (bit-identical)
     o = dispatch.paged_attention(q, ck, cv, page_table, pos, alive,
-                                 softcap=attn_softcap, scale=scale)
+                                 softcap=attn_softcap, scale=scale,
+                                 mesh=active_mesh())
     return qmatmul(p, "wo", o), PagedKVCache(k=ck, v=cv)
 
 
@@ -610,7 +612,8 @@ def gqa_decode_paged_quant(p, x_t, cache: QuantPagedKVCache, page_table,
     scale = query_scale if query_scale is not None else head_dim ** -0.5
     o = dispatch.paged_attention_quant(
         q, kw, vw, kcb, vcb, page_table, pos, alive, bits=kv_bits,
-        head_dim=head_dim, softcap=attn_softcap, scale=scale)
+        head_dim=head_dim, softcap=attn_softcap, scale=scale,
+        mesh=active_mesh())
     return (qmatmul(p, "wo", o),
             QuantPagedKVCache(k_words=kw, v_words=vw, k_cb=kcb, v_cb=vcb))
 
@@ -641,7 +644,7 @@ def mla_decode_paged_quant(p, x_t, cache: QuantPagedMLACache, page_table,
     ctx = dispatch.mla_paged_attention_quant(
         q_eff, q_rope, cw, rw, ccb, rcb, page_table, pos, alive,
         bits=kv_bits, kv_lora=kv_lora, rope_dim=rope_dim,
-        scale=(nope_dim + rope_dim) ** -0.5)
+        scale=(nope_dim + rope_dim) ** -0.5, mesh=active_mesh())
     w_uv = qweight(p, "w_uv").reshape(kv_lora, n_heads, v_dim)
     o = jnp.einsum("bqhl,lhd->bqhd", ctx, w_uv).reshape(b, 1, n_heads * v_dim)
     return (qmatmul(p, "wo", o),
@@ -731,7 +734,7 @@ def gqa_prefill_block_paged(p, x, cache: PagedKVCache, page_table, start,
     scale = query_scale if query_scale is not None else head_dim ** -0.5
     o = dispatch.blockwise_prefill_attention(
         q, view_k, view_v, t, jnp.arange(view_k.shape[1]),
-        softcap=attn_softcap, scale=scale)
+        softcap=attn_softcap, scale=scale, mesh=active_mesh())
     return (qmatmul(p, "wo", o.reshape(b, c, n_heads * head_dim)),
             PagedKVCache(k=ck, v=cv))
 
@@ -758,15 +761,16 @@ def gqa_prefill_block_paged_quant(p, x, cache: QuantPagedKVCache,
                                       start, alive, v, page_size, kv_bits,
                                       kv_cb_mode)
     masked_tbl = jnp.where(alive[:, None], page_table, 0)
-    kw_view = dispatch.page_gather(kw, page_table, alive)  # [B,cap,KV,Wd]
-    vw_view = dispatch.page_gather(vw, page_table, alive)
+    kw_view = _gather_slots(kw, page_table, alive)         # [B,cap,KV,Wd]
+    vw_view = _gather_slots(vw, page_table, alive)
     kcb_view = kcb[masked_tbl]                             # [B,npg,Gcb,K]
     vcb_view = vcb[masked_tbl]
     scale = query_scale if query_scale is not None else head_dim ** -0.5
     o = dispatch.blockwise_prefill_attention_quant(
         q, kw_view, vw_view, kcb_view, vcb_view, t,
         jnp.arange(kw_view.shape[1]), page_size=page_size, bits=kv_bits,
-        head_dim=head_dim, softcap=attn_softcap, scale=scale)
+        head_dim=head_dim, softcap=attn_softcap, scale=scale,
+        mesh=active_mesh())
     return (qmatmul(p, "wo", o.reshape(b, c, n_heads * head_dim)),
             QuantPagedKVCache(k_words=kw, v_words=vw, k_cb=kcb, v_cb=vcb))
 
@@ -805,7 +809,7 @@ def gqa_prefill_block_ring(p, x, cache: KVCache, start, *, n_heads, n_kv,
     scale = query_scale if query_scale is not None else head_dim ** -0.5
     o = dispatch.blockwise_prefill_attention(
         q, view_k, view_v, t, k_pos, window=window, softcap=attn_softcap,
-        scale=scale)
+        scale=scale, mesh=active_mesh())
 
     j = jnp.arange(cap)
     end = jnp.asarray(start) + c - 1
@@ -837,7 +841,7 @@ def gqa_prefill_block(p, x, buf_k, buf_v, start: int, *, n_heads, n_kv,
     scale = query_scale if query_scale is not None else head_dim ** -0.5
     o = dispatch.blockwise_prefill_attention(
         q, bk, bv, t, jnp.arange(bk.shape[1]), window=window,
-        softcap=attn_softcap, scale=scale)
+        softcap=attn_softcap, scale=scale, mesh=active_mesh())
     return qmatmul(p, "wo", o.reshape(b, c, n_heads * head_dim)), bk, bv
 
 
@@ -885,7 +889,8 @@ def _mla_block_attend(p, q_nope, q_rope, c_view, r_view, t, *, n_heads,
                                   (b, s, n_heads, rope_dim))], axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     return dispatch.blockwise_prefill_attention(
-        q, k, v, t, jnp.arange(s), scale=(nope_dim + rope_dim) ** -0.5)
+        q, k, v, t, jnp.arange(s), scale=(nope_dim + rope_dim) ** -0.5,
+        mesh=active_mesh())
 
 
 def mla_prefill_block_paged_quant(p, x, cache: QuantPagedMLACache,
@@ -915,9 +920,9 @@ def mla_prefill_block_paged_quant(p, x, cache: QuantPagedMLACache,
                                       start, alive, k_rope, page_size,
                                       kv_bits, "page")
     masked_tbl = jnp.where(alive[:, None], page_table, 0)
-    c_view = dequant_view_ref(dispatch.page_gather(cw, page_table, alive),
+    c_view = dequant_view_ref(_gather_slots(cw, page_table, alive),
                               ccb[masked_tbl], kv_lora, kv_bits, page_size)
-    r_view = dequant_view_ref(dispatch.page_gather(rw, page_table, alive),
+    r_view = dequant_view_ref(_gather_slots(rw, page_table, alive),
                               rcb[masked_tbl], rope_dim, kv_bits, page_size)
     o = _mla_block_attend(p, q_nope, q_rope, c_view.astype(ccb.dtype),
                           r_view.astype(rcb.dtype), t, n_heads=n_heads,
@@ -1002,7 +1007,7 @@ def mla_decode_paged(p, x_t, cache: PagedMLACache, page_table, pos, alive, *,
     # former gather + latent-softmax einsum chain, bit-identical)
     ctx = dispatch.mla_paged_attention(
         q_eff, q_rope, ckv, krope, page_table, pos, alive,
-        scale=(nope_dim + rope_dim) ** -0.5)
+        scale=(nope_dim + rope_dim) ** -0.5, mesh=active_mesh())
     w_uv = qweight(p, "w_uv").reshape(kv_lora, n_heads, v_dim)
     o = jnp.einsum("bqhl,lhd->bqhd", ctx, w_uv).reshape(b, 1, n_heads * v_dim)
     return qmatmul(p, "wo", o), PagedMLACache(c_kv=ckv, k_rope=krope)

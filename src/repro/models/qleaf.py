@@ -38,7 +38,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.models.sharding_ctx import active_mesh
+
 Array = jax.Array
+
+
+def _split(name: str) -> str:
+    """How the model axis holds weight ``name`` (``dist.sharding``'s
+    Megatron placement): "row" splits its input dim, "col" its output."""
+    from repro.dist.sharding import ROW_PARALLEL
+    return "row" if name in ROW_PARALLEL else "col"
 
 
 def has_leaf(p, name: str) -> bool:
@@ -73,11 +82,12 @@ def qmatmul(p, name: str, x: Array) -> Array:
         from repro.kernels import dispatch
         return dispatch.packed_quantized_matmul(
             x, p[f"{name}_pidx"], p[f"{name}_cb"],
-            layout=p[f"{name}_layout"])
+            layout=p[f"{name}_layout"], mesh=active_mesh(), split=_split(name))
     if f"{name}_idx" in p:
         from repro.kernels import dispatch
         return dispatch.quantized_matmul(x, p[f"{name}_idx"],
-                                         p[f"{name}_cb"])
+                                         p[f"{name}_cb"], mesh=active_mesh(),
+                                         split=_split(name))
     return x @ p[name]
 
 
@@ -97,7 +107,7 @@ def qmatmul_t(p, name: str, x: Array) -> Array:
         from repro.kernels import dispatch
         return dispatch.packed_quantized_matmul_t(
             x, p[f"{name}_pidx"], p[f"{name}_cb"],
-            layout=p[f"{name}_layout"])
+            layout=p[f"{name}_layout"], mesh=active_mesh())
     return x @ qweight(p, name).T
 
 
@@ -112,7 +122,8 @@ def qembed(p, name: str, tokens: Array) -> Array:
         from repro.kernels import dispatch
         return dispatch.quantized_gather(tokens, p[f"{name}_pidx"],
                                          p[f"{name}_cb"],
-                                         layout=p[f"{name}_layout"])
+                                         layout=p[f"{name}_layout"],
+                                         mesh=active_mesh())
     if f"{name}_idx" in p:
         idx = p[f"{name}_idx"][tokens].astype(jnp.int32)
         return p[f"{name}_cb"][idx]

@@ -87,6 +87,14 @@ def set_policy(policy: Optional[Policy]):
     _ACTIVE["policy"] = policy
 
 
+def active_mesh() -> Optional[Mesh]:
+    """The active policy's mesh (None without a policy or in "none"
+    mode).  Model call sites pass it to ``kernels.dispatch``, which maps
+    each Pallas route over it with that kernel's partition specs."""
+    pol: Optional[Policy] = _ACTIVE["policy"]
+    return None if pol is None or pol.mode == "none" else pol.mesh
+
+
 def constrain(x: jax.Array, *logical: Optional[str]) -> jax.Array:
     """Apply the active policy's sharding to ``x`` (no-op without one)."""
     pol: Optional[Policy] = _ACTIVE["policy"]

@@ -27,6 +27,7 @@ def run_sub(body: str) -> dict:
         import jax
         import jax.numpy as jnp
         import numpy as np
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
@@ -40,7 +41,7 @@ def test_sharded_kmeans_equals_single_device():
     res = run_sub("""
         from repro.dist.cstep import sharded_kmeans
         from repro.core.kmeans import kmeans_fit, quantile_init
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         w = jax.random.normal(jax.random.PRNGKey(0), (4096,))
         cb0 = quantile_init(w, 4)
         cb_d, assign_d, dist_d = sharded_kmeans(w, cb0, mesh, iters=20,
@@ -72,7 +73,7 @@ def test_histogram_warm_start_first_adaptive_cstep_1dev():
         from repro.core.kmeans import kmeans_fit, kmeans_plus_plus_init
         from repro.core.schemes import make_scheme
         scheme = make_scheme("adaptive:4")
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = make_mesh((1,), ("model",))
         w = jax.random.normal(jax.random.PRNGKey(0), (8192,))
         @partial(shard_map, mesh=mesh, in_specs=(P("model"),),
                  out_specs=(P("model"), P()), check_rep=False)
@@ -112,7 +113,7 @@ def test_histogram_ternary_scale_matches_exact():
         from jax.sharding import PartitionSpec as P
         from repro.dist.cstep import ternary_scale_histogram
         from repro.core.quant_ops import ternarize_scale
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         w = jax.random.normal(jax.random.PRNGKey(1), (8192,))
         @partial(shard_map, mesh=mesh, in_specs=P("model"),
                  out_specs=P(None), check_rep=False)
@@ -131,7 +132,7 @@ def test_compressed_psum_accuracy():
         from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist.cstep import compressed_psum
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         g = jax.random.normal(jax.random.PRNGKey(2), (8, 4096)) \
             * jnp.logspace(-2, 0, 8)[:, None]   # heterogeneous scales
         @partial(shard_map, mesh=mesh, in_specs=P("pod", None),
@@ -156,11 +157,11 @@ def test_elastic_checkpoint_reshard():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train import checkpoint as ckpt
         tmp = tempfile.mkdtemp()
-        mesh8 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh8 = make_mesh((2, 4), ("data", "model"))
         x = jnp.arange(64.0).reshape(8, 8)
         xs = jax.device_put(x, NamedSharding(mesh8, P(None, "model")))
         ckpt.save_checkpoint(tmp, 1, {"w": xs})
-        mesh4 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh4 = make_mesh((2, 2), ("data", "model"))
         sh = {"w": NamedSharding(mesh4, P("model", None))}
         out, _, _ = ckpt.restore_checkpoint(tmp, like={"w": x}, shardings=sh)
         ok = bool(np.allclose(np.asarray(out["w"]), np.asarray(x)))
@@ -173,7 +174,7 @@ def test_elastic_checkpoint_reshard():
 def test_param_sharding_rules_divisibility():
     res = run_sub("""
         from repro.dist.sharding import param_shardings
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         params = {
             "embed_tok": jnp.zeros((50281, 64)),      # 50281 % 4 != 0
             "stacks": ({"pos0": {"mixer": {"wq": jnp.zeros((2, 64, 64))},
@@ -236,7 +237,7 @@ def test_lc_c_step_sharded_equals_local_8dev():
         from repro.core import lc as lc_mod
         from repro.core.schemes import make_scheme
         from repro.dist.cstep import lc_c_step_sharded
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         scheme = make_scheme("adaptive:4")
         key = jax.random.PRNGKey(0)
         params = {
@@ -280,7 +281,7 @@ def test_adaptive_zero_sharded_c_step_8dev():
         from repro.core.schemes import make_scheme
         from repro.dist.cstep import (histogram_quantiles, lc_c_step_sharded,
                                       sharded_c_step)
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         scheme = make_scheme("adaptive_zero:4")
         key = jax.random.PRNGKey(0)
         params = {
@@ -340,6 +341,7 @@ def test_lctrainer_sharded_c_step_plan_flag_1dev():
     import numpy as np
 
     from repro.core import CompressionPlan, LCConfig
+    from repro.launch.mesh import make_mesh
     from repro.train.trainer import LCTrainer, TrainerConfig
 
     key = jax.random.PRNGKey(0)
@@ -358,7 +360,7 @@ def test_lctrainer_sharded_c_step_plan_flag_1dev():
     params = {"w": jax.random.normal(jax.random.fold_in(key, 2), (8, 8))}
     tc = TrainerConfig(lr=0.05, steps_per_l=5)
     lc = LCConfig(mu0=1e-2, mu_growth=1.5, num_lc_iters=3)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
 
     plan_sh = CompressionPlan.parse("adaptive:4", lc=lc,
                                     sharded_c_step=True,
@@ -397,7 +399,7 @@ def test_engine_paged_cache_sharding_rules():
             stacks=(StackSpec(pattern=(LayerKind("gqa", "dense"),),
                               groups=2),),
             tie_embeddings=True, q_chunk=8, kv_chunk=8, remat=False)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         n_slots, n_pages, page = 4, 8, 4
         caches = init_paged_cache(cfg, n_slots, n_pages, page)
         sh = engine_cache_shardings(caches, mesh, n_slots=n_slots,
@@ -444,7 +446,7 @@ def test_moe_ep_shard_map_equals_vmap():
         p = init_moe(key, 16, 8, 8, 1, "silu")
         x = jax.random.normal(key, (4, 16, 16))
         y_ref = apply_moe(p, x, top_k=2, capacity_factor=4.0)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         sharding_ctx.set_policy(sharding_ctx.Policy(mesh, mode="tp"))
         with mesh:
             y_ep = jax.jit(lambda p, x: apply_moe(p, x, top_k=2,
@@ -454,3 +456,143 @@ def test_moe_ep_shard_map_equals_vmap():
         print(json.dumps({"ok": ok}))
     """)
     assert res["ok"]
+
+
+def test_mesh_helper_auto_axes_and_constrain_2x4():
+    """launch.mesh builds Auto-axis meshes (JAX 0.9 defaults to Explicit,
+    which rejects sharding_ctx's constraints), and the activation policy's
+    specs apply on a 2x4 host mesh inside jit."""
+    res = run_sub("""
+        from jax.sharding import AxisType
+        from repro.launch.mesh import make_debug_mesh, mesh_from_spec
+        from repro.models import sharding_ctx
+        mesh = mesh_from_spec("2x4")
+        local = mesh_from_spec(None)
+        pol = sharding_ctx.Policy(mesh, mode="tp")
+
+        @jax.jit
+        def f(x):
+            with sharding_ctx.activation_policy(pol):
+                return sharding_ctx.constrain(x * 2, "batch", None, "heads")
+
+        with mesh:
+            y = f(jnp.ones((4, 3, 8)))
+        print(json.dumps({
+            "types": sorted({t.name for m in (mesh, local,
+                                              make_debug_mesh(2, 2, 2))
+                             for t in m.axis_types}),
+            "local": [list(local.axis_names), list(local.devices.shape)],
+            "spec": str(y.sharding.spec),
+            "ok": bool(np.all(np.asarray(y) == 2.0)),
+        }))
+    """)
+    assert res["types"] == ["Auto"]
+    assert res["local"] == [["data", "model"], [1, 8]]
+    assert res["spec"] == "PartitionSpec('data', None, 'model')"
+    assert res["ok"]
+
+
+def test_pallas_call_under_mesh_policy_2x4():
+    """Mosaic kernels cannot be partitioned by the compiler: the model
+    call sites pass the policy's mesh to dispatch, which runs the kernel
+    route in a shard_map with the weight's own split (column: N over
+    model; row: Kd over model + psum; other: replicated).  On sharded
+    operands inside jit each result must equal the unmapped call
+    (interpret mode here)."""
+    res = run_sub("""
+        os.environ["REPRO_KERNEL_BACKEND"] = "pallas_interpret"
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.core import compression as C
+        from repro.kernels import dispatch
+        from repro.models import qleaf, sharding_ctx
+        mesh = make_mesh((2, 4), ("data", "model"))
+        rng = np.random.RandomState(0)
+        idx = rng.randint(0, 16, size=(256, 512))
+        cb = jnp.asarray(np.sort(rng.randn(16)), jnp.float32)
+        pidx = jnp.asarray(C.pack_indices_2d(idx, 16))
+        layout = C.PackedLayout.make(256, 512, 16)
+        x = jnp.asarray(rng.randn(2, 4, 256), jnp.float32)
+        want = np.asarray(dispatch.packed_quantized_matmul(x, pidx, cb,
+                                                           layout=layout))
+        placed = {"w": P(None, "model"), "wo": P("model", None)}
+        out = {}
+        with mesh, sharding_ctx.activation_policy(
+                sharding_ctx.Policy(mesh, mode="tp")):
+            for name, spec in placed.items():
+                leaf = {f"{name}_pidx": jax.device_put(
+                            pidx, NamedSharding(mesh, spec)),
+                        f"{name}_cb": cb, f"{name}_layout": layout}
+                f = jax.jit(lambda x, leaf, n=name: qleaf.qmatmul(leaf, n, x))
+                out[name] = np.asarray(f(x, leaf))
+            out["replicated"] = np.asarray(jax.jit(
+                lambda x: dispatch.packed_quantized_matmul(
+                    x, pidx, cb, layout=layout, mesh=mesh))(x))
+        print(json.dumps({
+            "err": {k: float(np.max(np.abs(v - want)))
+                    for k, v in out.items()},
+            "scale": float(np.max(np.abs(want))),
+            "equal": [k for k, v in out.items()
+                      if np.array_equal(v, want)]}))
+    """)
+    assert "replicated" in res["equal"], res
+    # split operands change the kernel's block shapes, and the row split
+    # sums 4 partial products: f32 reordering only
+    assert max(res["err"].values()) <= 1e-5 * res["scale"], res
+
+
+def test_dispatch_tensor_parallel_routes_2x4():
+    """The vocabulary-split LM head and embedding gather and the
+    head-split paged decode and blockwise prefill, on a (2, 4) mesh in
+    interpret mode, equal their unmapped calls."""
+    res = run_sub("""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.core import compression as C
+        from repro.kernels import dispatch
+        mesh = make_mesh((2, 4), ("data", "model"))
+        rng = np.random.RandomState(1)
+        b = "pallas_interpret"
+        V, D, K = 64, 128, 16
+        idx = rng.randint(0, K, size=(V, D))
+        emb = jnp.asarray(C.pack_rows(idx, K))
+        lay = C.PackedLayout.make(V, D, K, order="row")
+        cb = jnp.asarray(np.sort(rng.randn(K)), jnp.float32)
+        x = jnp.asarray(rng.randn(3, D), jnp.float32)
+        toks = jnp.asarray(rng.randint(0, V, size=(2, 5)), jnp.int32)
+        H, KV, hd, page, n_pages, B = 8, 4, 16, 8, 6, 2
+        kp = jnp.asarray(rng.randn(n_pages + 1, page, KV, hd), jnp.float32)
+        vp = jnp.asarray(rng.randn(n_pages + 1, page, KV, hd), jnp.float32)
+        q = jnp.asarray(rng.randn(B, 1, H, hd), jnp.float32)
+        table = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
+        pos = jnp.asarray([13, 20], jnp.int32)
+        alive = jnp.ones((B,), bool)
+        qb = jnp.asarray(rng.randn(B, 8, H, hd), jnp.float32)
+        kb = jnp.asarray(rng.randn(B, 24, KV, hd), jnp.float32)
+        vb = jnp.asarray(rng.randn(B, 24, KV, hd), jnp.float32)
+        calls = {
+            "head": lambda m: dispatch.packed_quantized_matmul_t(
+                x, emb_s if m else emb, cb, layout=lay, backend=b, mesh=m),
+            "gather": lambda m: dispatch.quantized_gather(
+                toks, emb_s if m else emb, cb, layout=lay, backend=b,
+                mesh=m),
+            "paged": lambda m: dispatch.paged_attention(
+                q, kp_s if m else kp, vp_s if m else vp, table, pos, alive,
+                scale=0.25, backend=b, mesh=m),
+            "gather_pages": lambda m: dispatch.page_gather(
+                kp_s if m else kp, table, alive, backend=b, mesh=m),
+            "prefill": lambda m: dispatch.blockwise_prefill_attention(
+                qb, kb, vb, jnp.arange(16, 24), jnp.arange(24), scale=0.25,
+                backend=b, mesh=m),
+        }
+        emb_s = jax.device_put(emb, NamedSharding(mesh, P("model", None)))
+        heads = NamedSharding(mesh, P(None, None, "model", None))
+        kp_s, vp_s = jax.device_put(kp, heads), jax.device_put(vp, heads)
+        err = {}
+        for name, call in calls.items():
+            want = np.asarray(call(None))
+            with mesh:
+                got = np.asarray(jax.jit(lambda: call(mesh))())
+            err[name] = float(np.max(np.abs(got - want)))
+        print(json.dumps(err))
+    """)
+    assert res == {k: 0.0 for k in ("head", "gather", "paged",
+                                    "gather_pages", "prefill")}, res

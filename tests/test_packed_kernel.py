@@ -4,9 +4,8 @@ in-kernel unpack is bit-exact, and the three packed kernels
 interpret mode) match the dense-gather oracle for bits ∈ {1, 2, 4, 8},
 non-pow2 K, and ragged M/Kd/N tails.  Deterministic sweeps always run;
 hypothesis fuzzing skips when hypothesis is not installed (``pip install
--r requirements-dev.txt``), like test_schemes_property.py.  Tests marked
-``tpu`` compile the same kernels with Mosaic and only run on a real TPU
-backend."""
+-r requirements-dev.txt``), like test_schemes_property.py.  The Mosaic
+compiles of the same kernels live in test_tpu_compile.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,6 +18,7 @@ except ImportError:        # dev-only dep: fuzzing skips, sweeps still run
 
 from repro.core import compression as C
 from repro.kernels import dispatch, ops, ref
+from repro.kernels.unpack import dequant_tile
 
 # K values spanning bits ∈ {1, 2, 3, 4, 8}, pow2 and non-pow2.
 K_SWEEP = [2, 3, 4, 5, 16, 200, 256]
@@ -73,29 +73,28 @@ def test_packed_matmul_matches_ref(m, kd, n, k):
                                rtol=3e-5, atol=3e-4)
 
 
-@pytest.mark.parametrize("dequant", ["lut", "onehot"])
-def test_dequant_strategies_agree(dequant):
-    idx, pidx, cb = _rand_case(16, 256, 64, seed=7)
-    x = jnp.asarray(np.random.RandomState(9).randn(16, 256), jnp.float32)
-    y = ops.packed_codebook_matmul(x, pidx, cb, bm=16, bn=64, bk=64,
-                                   dequant=dequant)
-    y2 = ref.codebook_matmul_ref(x, jnp.asarray(idx), cb)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y2),
-                               rtol=3e-5, atol=3e-4)
+@pytest.mark.parametrize("k", [2, 16, 256])
+def test_dequant_tile_matches_take(k):
+    """The compare+select dequant every kernel shares == cb[idx],
+    bitwise (it only selects codebook entries)."""
+    rng = np.random.RandomState(k)
+    idx = jnp.asarray(rng.randint(0, k, size=(24, 40)), jnp.int32)
+    cb = jnp.asarray(rng.randn(k), jnp.float32)
+    out = dequant_tile(idx, cb[None, :])
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(cb)[np.asarray(idx)])
 
 
-def test_uint8_kernel_lut_matches_onehot():
-    """The uint8-index kernel grew the same LUT/one-hot switch."""
+def test_uint8_kernel_matches_ref():
+    """The uint8-index kernel over a K=256 codebook == dequant-then-dot."""
     rng = np.random.RandomState(3)
     x = jnp.asarray(rng.randn(24, 128), jnp.float32)
     idx = jnp.asarray(rng.randint(0, 256, size=(128, 48)), jnp.uint8)
     cb = jnp.asarray(rng.randn(256), jnp.float32)
-    y_lut = ops.codebook_matmul(x, idx, cb, bm=32, bn=32, bk=64,
-                                dequant="lut")
-    y_oh = ops.codebook_matmul(x, idx, cb, bm=32, bn=32, bk=64,
-                               dequant="onehot")
-    np.testing.assert_allclose(np.asarray(y_lut), np.asarray(y_oh),
-                               rtol=1e-6, atol=1e-5)
+    y = ops.codebook_matmul(x, idx, cb, bm=32, bn=32, bk=64)
+    y_ref = ref.codebook_matmul_ref(x, idx, cb)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                               rtol=1e-5, atol=1e-4)
 
 
 @pytest.mark.parametrize("k", K_SWEEP)
@@ -134,41 +133,14 @@ def test_packed_matmul_t_matches_ref(m, v, d, k, order):
 @pytest.mark.parametrize("k", K_SWEEP)
 def test_gather_kernel_matches_dense_rows_bitwise(k):
     """interpret-mode gather kernel == dense-table row gather, bitwise
-    (a pure gather — no arithmetic), ragged D included; lut == onehot."""
+    (a pure gather — no arithmetic), ragged D included."""
     v, d = 50, 13
     idx, _, cb = _rand_case(k, v, d, seed=k)
     pidx = jnp.asarray(C.pack_rows(idx, k))
     toks = jnp.asarray(np.random.RandomState(k).randint(0, v, size=(9,)),
                        jnp.int32)
     dense = np.asarray(cb)[idx]
-    for dequant in ("lut", "onehot"):
-        g = ops.quantized_gather(toks, pidx, cb, d, dequant=dequant)
-        np.testing.assert_array_equal(np.asarray(g),
-                                      dense[np.asarray(toks)])
-
-
-@pytest.mark.tpu
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="Mosaic compile path needs a real TPU")
-def test_packed_kernels_compile_on_tpu():
-    """The Mosaic (non-interpret) lowering of all three packed kernels —
-    the compiled counterpart of the interpret-mode sweeps above."""
-    k, v, d, m = 16, 256, 512, 8
-    idx, _, cb = _rand_case(k, v, d, seed=1)
-    x = jnp.asarray(np.random.RandomState(0).randn(m, d), jnp.float32)
-    dense = np.asarray(cb)[idx]
-    pidx_kd = jnp.asarray(C.pack_indices_2d(idx, k))
-    y = ops.packed_codebook_matmul(
-        jnp.asarray(np.random.RandomState(2).randn(m, v), jnp.float32),
-        pidx_kd, cb, bm=8, bn=128, bk=128, interpret=False)
-    assert y.shape == (m, d)
-    pidx_r = jnp.asarray(C.pack_rows(idx, k))
-    y_t = ops.packed_codebook_matmul_t(x, pidx_r, cb, v, order="row", bm=8,
-                                       bn=128, bk=128, interpret=False)
-    np.testing.assert_allclose(np.asarray(y_t), np.asarray(x) @ dense.T,
-                               rtol=3e-5, atol=3e-4)
-    toks = jnp.asarray([0, 3, 255, 17], jnp.int32)
-    g = ops.quantized_gather(toks, pidx_r, cb, d, interpret=False)
+    g = ops.quantized_gather(toks, pidx, cb, d)
     np.testing.assert_array_equal(np.asarray(g), dense[np.asarray(toks)])
 
 

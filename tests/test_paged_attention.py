@@ -9,7 +9,7 @@ Layered oracles, each proved against the one below it:
 2. quant refs == dense refs **bit-exactly** when the dense ref runs on
    the dequantized pools ({gqa, mla}, page- and head-grouped codebooks)
    — quantization and attention commute by construction;
-3. Pallas kernels (interpret mode; ``-m tpu`` variants compile the
+3. Pallas kernels (interpret mode; test_tpu_compile.py compiles the
    Mosaic lowering) ≈ the jnp refs for dense and quantized pages;
 4. one decode step over quantized pages stays within the codebook
    distortion bound of the dense step on the original values (tighter
@@ -420,44 +420,3 @@ def test_engine_rejects_bad_kv_knobs():
     with pytest.raises(ValueError, match="kv_cb_mode"):
         Engine(sp, cfg, n_slots=2, page_size=8, max_seq=24, kv_bits=4,
                kv_cb_mode="tensor")
-
-
-# ---------------------------------------------------------------------------
-# Mosaic compile variants (need a real TPU; CI runs them allowed-to-skip)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.tpu
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="Mosaic compile path needs a real TPU")
-def test_paged_kernels_compile_on_tpu():
-    q, kp, vp, tbl, pos, alive = _gqa_case()
-    out = ops.paged_attention(q, kp, vp, tbl, pos, alive, softcap=None,
-                              scale=SCALE, token_tile=PAGE,
-                              interpret=False)
-    assert out.shape == (B, 1, H * HD)
-    kw, kcb, _ = _quant_pool(kp, 4)
-    vw, vcb, _ = _quant_pool(vp, 4)
-    out = ops.paged_attention_quant(
-        q, kw, vw, kcb, vcb, tbl, pos, alive, bits=4, head_dim=HD,
-        softcap=None, scale=SCALE, token_tile=PAGE, interpret=False)
-    assert out.shape == (B, 1, H * HD)
-    g = ops.page_gather(kp, tbl, alive, interpret=False)
-    assert g.shape == (B, NPG * PAGE, KV, HD)
-
-
-@pytest.mark.tpu
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="Mosaic compile path needs a real TPU")
-def test_mla_paged_kernels_compile_on_tpu():
-    qe, qr, cp, rp, tbl, pos, alive = _mla_case()
-    scale = (LAT + RD) ** -0.5
-    out = ops.mla_paged_attention(qe, qr, cp, rp, tbl, pos, alive,
-                                  scale=scale, token_tile=PAGE,
-                                  interpret=False)
-    assert out.shape == (B, 1, H, LAT)
-    cw, ccb, _ = _quant_pool(cp, 4)
-    rw, rcb, _ = _quant_pool(rp, 4)
-    out = ops.mla_paged_attention_quant(
-        qe, qr, cw, rw, ccb, rcb, tbl, pos, alive, bits=4, kv_lora=LAT,
-        rope_dim=RD, scale=scale, token_tile=PAGE, interpret=False)
-    assert out.shape == (B, 1, H, LAT)
