@@ -47,6 +47,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.core import kvquant
 from repro.engine import sampling
@@ -70,9 +71,10 @@ def _decode_and_sample(params, cfg, caches, page_table, tokens_t, pos,
     """
     logits, caches = decode_step_slots(params, cfg, caches, page_table,
                                        tokens_t, pos, alive)
-    row = logits[:, 0]
-    row = jnp.where(poison[:, None], jnp.full_like(row, jnp.nan), row)
-    nxt, bad = sampling.sample_and_flag(row, temps, top_ks, keys)
+    with jax.named_scope("sample"):
+        row = logits[:, 0]
+        row = jnp.where(poison[:, None], jnp.full_like(row, jnp.nan), row)
+        nxt, bad = sampling.sample_and_flag(row, temps, top_ks, keys)
     return nxt, bad, caches
 
 
@@ -366,6 +368,13 @@ class Engine:
     # -- one step -----------------------------------------------------------
 
     def step(self) -> dict:
+        """One engine step.  Its phases are host spans on the profiler's
+        clock (``engine.*``, ``jax.profiler.TraceAnnotation``): they cost
+        one activity check each while no profiler session is open."""
+        with span("engine.step", step=self.stats.steps + 1):
+            return self._step()
+
+    def _step(self) -> dict:
         st = self.stats
         st.steps += 1
         st.occupancy_sum += self.sched.occupancy()
@@ -374,17 +383,16 @@ class Engine:
                 "quarantined": 0}
         budget = self.token_budget
 
-        # 0) deadline sweep: expired requests (queued or in-flight) free
-        #    their slot/pages before any work is scheduled this step
-        self._expire_deadlines(info)
-
-        # 1) decode every running slot whose next page is available
-        running = self.sched.running_ids()
-        ready, stalled = [], []
-        for i in running:
-            s = self.sched.slots[i]
-            (ready if self.pool.ensure(i, s.write_pos)
-             else stalled).append(i)
+        with span("engine.schedule"):
+            # 0) deadline sweep: expired requests (queued or in-flight)
+            #    free their slot/pages before any work is scheduled
+            self._expire_deadlines(info)
+            # 1) decode every running slot whose next page is available
+            ready, stalled = [], []
+            for i in self.sched.running_ids():
+                s = self.sched.slots[i]
+                (ready if self.pool.ensure(i, s.write_pos)
+                 else stalled).append(i)
         if stalled:
             st.stall_events += len(stalled)
             info["stalled"] = len(stalled)
@@ -394,17 +402,18 @@ class Engine:
             st.decode_tokens += len(ready)
 
         # 2) admit queued requests into free slots (reserve prompt pages)
-        for i in self.sched.free_ids():
-            if not self.sched.queue:
-                break
-            req = self.sched.queue[0]
-            if not self.pool.alloc(i, self.pool.pages_for_len(
-                    req.prompt_len)):
-                break
-            self.sched.queue.popleft()
-            self.sched.admit(i, req)
-            st.admitted += 1
-            info["admitted"] += 1
+        with span("engine.admit"):
+            for i in self.sched.free_ids():
+                if not self.sched.queue:
+                    break
+                req = self.sched.queue[0]
+                if not self.pool.alloc(i, self.pool.pages_for_len(
+                        req.prompt_len)):
+                    break
+                self.sched.queue.popleft()
+                self.sched.admit(i, req)
+                st.admitted += 1
+                info["admitted"] += 1
 
         # 3) blockwise prefill under the leftover budget: each prefilling
         #    slot advances at most one block per step, and only when the
@@ -486,41 +495,46 @@ class Engine:
         return self._table_cache[1]
 
     def _decode_ready(self, ready, info):
-        b = self.n_slots
-        tokens = np.zeros((b, 1), np.int32)
-        pos = np.zeros((b,), np.int32)
-        alive = np.zeros((b,), bool)
-        temps = np.zeros((b,), np.float32)
-        top_ks = np.zeros((b,), np.int32)
-        keys = np.zeros((b, 2), np.uint32)
-        for i in ready:
-            s = self.sched.slots[i]
-            tokens[i, 0] = s.out[-1]
-            pos[i] = s.write_pos
-            alive[i] = True
-            temps[i] = s.req.temperature
-            top_ks[i] = s.req.top_k
-            keys[i] = (np.asarray(sampling.slot_key(s.req.seed,
-                                                    s.n_generated))
-                       if s.req.temperature > 0 else self._zero_key)
-        poison = (self._poison_mask if self._poison_mask is not None
-                  else self._no_poison)
-        self._poison_mask = None           # one-shot injection
-        nxt, bad, self.caches = self._decode(
-            self.params, self.cfg, self.caches, self._page_table(),
-            jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(alive),
-            jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(keys),
-            jnp.asarray(poison))
-        nxt, bad = np.asarray(nxt), np.asarray(bad)
-        for i in ready:
-            if bad[i]:
-                self._quarantine(i, info)
-                continue
-            s = self.sched.slots[i]
-            s.out.append(int(nxt[i]))
-            info["decoded"] += 1
-            if s.finished():
-                self._finish(i, info)
+        with span("engine.decode.prep", live=len(ready)):
+            b = self.n_slots
+            tokens = np.zeros((b, 1), np.int32)
+            pos = np.zeros((b,), np.int32)
+            alive = np.zeros((b,), bool)
+            temps = np.zeros((b,), np.float32)
+            top_ks = np.zeros((b,), np.int32)
+            keys = np.zeros((b, 2), np.uint32)
+            for i in ready:
+                s = self.sched.slots[i]
+                tokens[i, 0] = s.out[-1]
+                pos[i] = s.write_pos
+                alive[i] = True
+                temps[i] = s.req.temperature
+                top_ks[i] = s.req.top_k
+                keys[i] = (np.asarray(sampling.slot_key(s.req.seed,
+                                                        s.n_generated))
+                           if s.req.temperature > 0 else self._zero_key)
+            poison = (self._poison_mask if self._poison_mask is not None
+                      else self._no_poison)
+            self._poison_mask = None           # one-shot injection
+            args = (self._page_table(), jnp.asarray(tokens),
+                    jnp.asarray(pos), jnp.asarray(alive),
+                    jnp.asarray(temps), jnp.asarray(top_ks),
+                    jnp.asarray(keys), jnp.asarray(poison))
+        with span("engine.decode.launch"):
+            nxt, bad, self.caches = self._decode(self.params, self.cfg,
+                                                 self.caches, *args)
+        with span("engine.decode.fetch"):
+            nxt, bad = np.asarray(nxt), np.asarray(bad)
+        with span("engine.decode.commit"):
+            for i in ready:
+                if bad[i]:
+                    self._quarantine(i, info)
+                    continue
+                s = self.sched.slots[i]
+                s.out.append(int(nxt[i]))
+                info["decoded"] += 1
+                if s.finished():
+                    self._finish(i, info)
 
     def _quarantine(self, i, info):
         """Isolate a slot whose logits went non-finite: typed ``FAILED``
@@ -542,11 +556,14 @@ class Engine:
         block the request's first token is sampled from the block's
         last-position logits — the same row the one-shot oracle's
         blockwise prefill produces, so streams stay bit-exact."""
-        start = s.prefill_progress
-        tok = jnp.asarray(s.req.prompt[None, start:start + blk], jnp.int32)
-        logits, self.caches = self._chunk(
-            self.params, self.cfg, self.caches, self._page_table(), tok,
-            jnp.asarray(i, jnp.int32), jnp.asarray(start, jnp.int32))
+        start, rid = s.prefill_progress, s.req.rid
+        with span("engine.prefill.launch", rid=rid, start=start, width=blk):
+            tok = jnp.asarray(s.req.prompt[None, start:start + blk],
+                              jnp.int32)
+            logits, self.caches = self._chunk(
+                self.params, self.cfg, self.caches, self._page_table(),
+                tok, jnp.asarray(i, jnp.int32),
+                jnp.asarray(start, jnp.int32))
         s.prefill_progress += blk
         self.stats.prefill_calls += 1
         self.stats.prefill_tokens += blk
@@ -555,16 +572,19 @@ class Engine:
             return
         key = (np.asarray(sampling.slot_key(s.req.seed, 0))
                if s.req.temperature > 0 else self._zero_key)
-        tok0, bad = self._sample(
-            logits[:, -1], jnp.asarray([s.req.temperature], jnp.float32),
-            jnp.asarray([s.req.top_k], jnp.int32),
-            jnp.asarray(key[None, :]))
+        with span("engine.prefill.fetch", rid=rid):
+            tok0, bad = self._sample(
+                logits[:, -1],
+                jnp.asarray([s.req.temperature], jnp.float32),
+                jnp.asarray([s.req.top_k], jnp.int32),
+                jnp.asarray(key[None, :]))
+            tok0, bad = int(np.asarray(tok0)[0]), bool(np.asarray(bad)[0])
         self.stats.prefill_samples += 1
         s.prefilled = True
-        if bool(np.asarray(bad)[0]):
+        if bad:
             self._quarantine(i, info)
             return
-        s.out.append(int(np.asarray(tok0)[0]))
+        s.out.append(tok0)
         if s.finished():
             self._finish(i, info)
 

@@ -581,23 +581,27 @@ def _apply_mixer_decode_slots(kind, p, x_t, cache, page_table, pos, alive,
 
 def _apply_layer_decode_slots(kind, p, x_t, cache, page_table, pos, alive,
                               cfg):
-    h = L.rms_norm(x_t, p["ln1_norm_scale"])
-    out, cache = _apply_mixer_decode_slots(kind, p["mixer"], h, cache,
-                                           page_table, pos, alive, cfg)
-    if cfg.post_norms:
-        out = L.rms_norm(out, p["post1_norm_scale"])
-    x_t = x_t + out
-    if kind.mlp != "none":
-        h = L.rms_norm(x_t, p["ln2_norm_scale"])
-        if kind.mlp == "moe":
-            out = moe_mod.apply_moe(p["mlp"], h, top_k=cfg.moe.top_k,
-                                    act=cfg.mlp_act,
-                                    capacity_factor=cfg.moe.capacity_factor)
-        else:
-            out = L.apply_mlp(p["mlp"], h, cfg.mlp_act)
+    # named scopes: the device ops of each region carry them in the
+    # profiler's trace (the mixer's scope is "attn" for every kind)
+    with jax.named_scope("attn"):
+        h = L.rms_norm(x_t, p["ln1_norm_scale"])
+        out, cache = _apply_mixer_decode_slots(kind, p["mixer"], h, cache,
+                                               page_table, pos, alive, cfg)
         if cfg.post_norms:
-            out = L.rms_norm(out, p["post2_norm_scale"])
+            out = L.rms_norm(out, p["post1_norm_scale"])
         x_t = x_t + out
+    if kind.mlp != "none":
+        with jax.named_scope("mlp"):
+            h = L.rms_norm(x_t, p["ln2_norm_scale"])
+            if kind.mlp == "moe":
+                out = moe_mod.apply_moe(
+                    p["mlp"], h, top_k=cfg.moe.top_k, act=cfg.mlp_act,
+                    capacity_factor=cfg.moe.capacity_factor)
+            else:
+                out = L.apply_mlp(p["mlp"], h, cfg.mlp_act)
+            if cfg.post_norms:
+                out = L.rms_norm(out, p["post2_norm_scale"])
+            x_t = x_t + out
     return x_t, cache
 
 
@@ -613,12 +617,13 @@ def decode_step_slots(params, cfg: ModelConfig, caches, page_table,
     are independent of which slots are live, so admission never
     recompiles.
     """
-    x = Q.qembed(params, "embed_tok", tokens_t)
-    if cfg.emb_scale is not None:
-        x = x * jnp.asarray(cfg.emb_scale, x.dtype)
-    if cfg.pos_embed == "sinusoidal":
-        x = x + L.sinusoidal_positions(pos[:, None],
-                                       cfg.d_model).astype(x.dtype)
+    with jax.named_scope("embed"):
+        x = Q.qembed(params, "embed_tok", tokens_t)
+        if cfg.emb_scale is not None:
+            x = x * jnp.asarray(cfg.emb_scale, x.dtype)
+        if cfg.pos_embed == "sinusoidal":
+            x = x + L.sinusoidal_positions(pos[:, None],
+                                           cfg.d_model).astype(x.dtype)
 
     new_caches = []
     for spec, sp, sc in zip(cfg.stacks, params["stacks"], caches):
@@ -635,7 +640,9 @@ def decode_step_slots(params, cfg: ModelConfig, caches, page_table,
 
         x, nc = jax.lax.scan(body, x, (sp, sc))
         new_caches.append(nc)
-    return _head(params, cfg, x), tuple(new_caches)
+    with jax.named_scope("head"):
+        logits = _head(params, cfg, x)
+    return logits, tuple(new_caches)
 
 
 # Default prompt-block length for the one-shot (oracle) blockwise
@@ -925,26 +932,28 @@ def prefill_chunk_slots(params, cfg: ModelConfig, caches, page_table,
     slot's pages; recurrent state (ring / SSM / RG-LRU rows) advances in
     place.  Returns (last-position logits [1, 1, V] f32, new caches) —
     the logits are only meaningful on the prompt's final block, where
-    they seed the first sampled token.
+    they seed the first sampled token.  Its device ops carry the named
+    scope ``prefill``.
     """
-    c = tokens_c.shape[1]
-    sl = jnp.asarray(slot, jnp.int32).reshape(1)
-    start = jnp.asarray(start, jnp.int32)
-    alive = jnp.ones((1,), bool)
-    table_row = jnp.take(page_table, sl, axis=0)
-    x = _embed(params, cfg, tokens_c, positions=start + jnp.arange(c))
-    new_caches = []
-    for spec, sp, sc in zip(cfg.stacks, params["stacks"], caches):
-        def body(h, xs):
-            gp, gc = xs
-            ngc = {}
-            for pi, kind in enumerate(spec.pattern):
-                h, cc = _apply_layer_prefill_slot(
-                    kind, gp[f"pos{pi}"], h, gc[f"pos{pi}"], table_row,
-                    sl, start, alive, cfg)
-                ngc[f"pos{pi}"] = cc
-            return h, ngc
+    with jax.named_scope("prefill"):
+        c = tokens_c.shape[1]
+        sl = jnp.asarray(slot, jnp.int32).reshape(1)
+        start = jnp.asarray(start, jnp.int32)
+        alive = jnp.ones((1,), bool)
+        table_row = jnp.take(page_table, sl, axis=0)
+        x = _embed(params, cfg, tokens_c, positions=start + jnp.arange(c))
+        new_caches = []
+        for spec, sp, sc in zip(cfg.stacks, params["stacks"], caches):
+            def body(h, xs):
+                gp, gc = xs
+                ngc = {}
+                for pi, kind in enumerate(spec.pattern):
+                    h, cc = _apply_layer_prefill_slot(
+                        kind, gp[f"pos{pi}"], h, gc[f"pos{pi}"], table_row,
+                        sl, start, alive, cfg)
+                    ngc[f"pos{pi}"] = cc
+                return h, ngc
 
-        x, nc = jax.lax.scan(body, x, (sp, sc))
-        new_caches.append(nc)
-    return _head(params, cfg, x[:, -1:, :]), tuple(new_caches)
+            x, nc = jax.lax.scan(body, x, (sp, sc))
+            new_caches.append(nc)
+        return _head(params, cfg, x[:, -1:, :]), tuple(new_caches)

@@ -119,17 +119,22 @@ def test_engine_eos_early_exit_out_of_order():
     base = [Request(rid=r, prompt=p16[r], max_new_tokens=8)
             for r in range(3)]
     plain = _oracle(params, cfg, base)
-    # make request 1's third token its EOS: it must finish after 3 tokens
-    eos = int(plain[1][2])
+    # request 1's EOS: the first token at index >= 2 (and before the
+    # last) that does not occur earlier in its greedy stream, so the
+    # stream stops just after it, mid-stream
+    s = [int(t) for t in plain[1]]
+    cut = next(i for i in range(2, len(s) - 1) if s[i] not in s[:i])
+    eos = s[cut]
     reqs = [Request(rid=r, prompt=p16[r], max_new_tokens=8,
                     eos_id=eos if r == 1 else None) for r in range(3)]
     want = _oracle(params, cfg, reqs)
-    assert len(want[1]) == 3
+    assert len(want[1]) == cut + 1
 
     eng = Engine(params, cfg, n_slots=2, page_size=8, max_seq=24)
     outs = eng.run(reqs)
     _assert_streams_equal(outs, want)
-    assert len(outs[1]) == 3 and outs[1][-1] == eos
+    assert len(outs[1]) == cut + 1 and outs[1][-1] == eos
+    assert len(outs[0]) == len(outs[2]) == 8
 
 
 @pytest.mark.parametrize("arch", ["deepseek-v2-lite-16b",
