@@ -218,9 +218,13 @@ def run():
         warmup=2, iters=5)
     rows.append(("paged_attention_gqa_ref_dense", us,
                  f"{note}; jnp gather+softmax oracle"))
-    us = time_call(lambda *a: ops.paged_attention(
-        *a, softcap=None, scale=scale6, token_tile=tile, interpret=True),
-        q6, kp, vp, tbl6, pos6, alive6, warmup=1, iters=2)
+    # the kernel reads the engine's stored form: one layer of lane-dense
+    # pools [G, P+1, page, KV·hd]
+    us = time_call(lambda q, k, v, *a: ops.paged_attention(
+        q, k, v, jnp.zeros((), jnp.int32), *a, softcap=None, scale=scale6,
+        token_tile=tile, interpret=True),
+        q6, kp.reshape(1, pp1, page6, -1), vp.reshape(1, pp1, page6, -1),
+        tbl6, pos6, alive6, warmup=1, iters=2)
     rows.append(("paged_attention_gqa_interp_dense", us,
                  f"{note}; scalar-prefetch fused kernel, interpret-mode"))
 

@@ -121,17 +121,21 @@ def batch_shardings(batch: PyTree, mesh: Mesh) -> PyTree:
 
 
 def engine_cache_shardings(caches: PyTree, mesh: Mesh, *, n_slots: int,
-                           n_pages: Optional[int] = None) -> PyTree:
+                           n_pages: Optional[int] = None,
+                           n_kv: Optional[int] = None) -> PyTree:
     """Shardings for the engine's paged/slot caches (``init_paged_cache``).
 
-    Two leaf families, told apart by the second axis:
+    Told apart by the cache's type, then by the second axis:
 
-    * page pools (``[G, n_pages + 1, page, ...]``) — the page axis
-      **replicates** over the data axes: any slot's page-table entry may
-      point at any physical page, so pages cannot be partitioned by
-      batch.  The kv-head axis of 5-D pools shards over ``model`` (the
-      head's pages live with its projection shard); MLA latent pools
-      (4-D) replicate;
+    * dense KV pools (``PagedKVCache``, lane-dense ``[G, n_pages + 1,
+      page, KV·hd]``) — the page axis **replicates** over the data axes:
+      any slot's page-table entry may point at any physical page, so
+      pages cannot be partitioned by batch.  The lanes split over
+      ``model`` by whole kv heads (the head's pages live with its
+      projection shard) when ``n_kv`` divides the axis;
+    * other page pools (``[G, n_pages + 1, page, ...]``) replicate their
+      pages too; the kv-head axis of 5-D quantized word pools shards over
+      ``model``; MLA latent pools (4-D) replicate;
     * per-slot state (``shape[1] == n_slots``: SSM/RG-LRU state, conv
       tails, sliding-window ring buffers) — the slot axis shards over
       the data axes exactly like a decode batch, and 5-D KV-style leaves
@@ -140,8 +144,11 @@ def engine_cache_shardings(caches: PyTree, mesh: Mesh, *, n_slots: int,
     Pass ``n_pages`` so the pool check wins when ``n_pages + 1 ==
     n_slots`` (an oversubscribed pool could otherwise be mistaken for
     slot state and have its pages data-sharded; replication is the
-    always-correct fallback).
+    always-correct fallback), and ``n_kv`` (the model's kv heads) so
+    dense pools can split their lanes.
     """
+    from repro.models.attention import PagedKVCache
+
     daxes = batch_axes(mesh)
     dsize = _axis_size(mesh, daxes)
     model = mesh.shape.get("model", 1) if "model" in mesh.axis_names else 1
@@ -158,7 +165,18 @@ def engine_cache_shardings(caches: PyTree, mesh: Mesh, *, n_slots: int,
             parts[3] = "model"
         return NamedSharding(mesh, P(*parts))
 
-    return jax.tree_util.tree_map(rule, caches)
+    def dense_pool(leaf):
+        split = model > 1 and n_kv is not None and n_kv % model == 0
+        return NamedSharding(mesh, P(*[None] * (leaf.ndim - 1),
+                                     "model" if split else None))
+
+    def node(x):
+        if isinstance(x, PagedKVCache):
+            return jax.tree_util.tree_map(dense_pool, x)
+        return rule(x)
+
+    return jax.tree_util.tree_map(
+        node, caches, is_leaf=lambda x: isinstance(x, PagedKVCache))
 
 
 def cache_shardings(caches: PyTree, mesh: Mesh) -> PyTree:

@@ -238,7 +238,8 @@ class Engine:
             from repro.dist import sharding as shard_rules
             sh = shard_rules.engine_cache_shardings(self.caches, mesh,
                                                     n_slots=n_slots,
-                                                    n_pages=n_pages)
+                                                    n_pages=n_pages,
+                                                    n_kv=cfg.n_kv)
             self.caches = jax.tree_util.tree_map(jax.device_put,
                                                  self.caches, sh)
         self._decode = _DECODE

@@ -297,35 +297,47 @@ def paged_token_tile(kind: str, feat: int, page_size: int, kv_bits: int
     return tile
 
 
-def paged_attention(q: Array, k_pool: Array, v_pool: Array,
+def paged_attention(q: Array, k_pool: Array, v_pool: Array, layer,
                     page_table: Array, pos: Array, alive: Array, *,
                     softcap: Optional[float] = None, scale: float,
                     backend: Optional[str] = None,
                     mesh: Optional[Mesh] = None) -> Array:
-    """Paged GQA decode over dense KV pages: q [B,1,H,hd] + pools
-    [P+1, page, KV, hd] → [B, 1, H·hd].
+    """Paged GQA decode over dense KV pages: q [B,1,H,hd] + the stacked
+    lane-dense pools [G, P+1, page, KV·hd] at ``layer`` → [B, 1, H·hd].
 
-    ``ref`` (CPU serving default): the jnp gather + masked-softmax math
-    that used to live inline in ``models.attention`` — bit-identical to
-    it.  Pallas backends: the fused scalar-prefetch online-softmax
-    kernel (``kernels.paged_attention``), allclose vs ref."""
+    ``ref`` (CPU serving default): the layer's pool as [P+1, page, KV,
+    hd] through the jnp gather + masked-softmax math that used to live
+    inline in ``models.attention`` — bit-identical to it.  Pallas
+    backends: the fused scalar-prefetch online-softmax kernel
+    (``kernels.paged_attention``), which reads the stacked pool where it
+    lies, allclose vs ref.  On a mesh the heads split, the pools' lanes
+    with them (whole kv heads per device)."""
     b = backend or default_backend()
     m = _model_size(mesh, b)
+    hd = q.shape[3]
+    kv = k_pool.shape[3] // hd
     if m:
-        split = q.shape[2] % m == 0 and k_pool.shape[2] % m == 0
-        h4 = _heads_spec(4, 2, split)
+        split = q.shape[2] % m == 0 and kv % m == 0
         body = functools.partial(paged_attention, softcap=softcap,
                                  scale=scale, backend=b)
-        return _shard_call(mesh, body, (h4, h4, h4, P(), P(), P()),
+        pools = _heads_spec(4, 3, split)
+        return _shard_call(mesh, body,
+                           (_heads_spec(4, 2, split), pools, pools, P(),
+                            P(), P(), P()),
                            _heads_spec(3, 2, split), q, k_pool, v_pool,
-                           page_table, pos, alive)
+                           layer, page_table, pos, alive)
     if b == "ref":
-        return ref.paged_attention_ref(q, k_pool, v_pool, page_table, pos,
-                                       alive, softcap=softcap, scale=scale)
-    page, kv, hd = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
+        def one(pool):
+            lp = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+            return lp.reshape(lp.shape[:2] + (kv, hd))
+        return ref.paged_attention_ref(q, one(k_pool), one(v_pool),
+                                       page_table, pos, alive,
+                                       softcap=softcap, scale=scale)
+    page = k_pool.shape[2]
     tile = paged_token_tile("gqa", kv * hd, page, 0)
-    out = ops.paged_attention(q, k_pool, v_pool, page_table, pos, alive,
-                              softcap=softcap, scale=scale, token_tile=tile,
+    out = ops.paged_attention(q, k_pool, v_pool, layer, page_table, pos,
+                              alive, softcap=softcap, scale=scale,
+                              token_tile=tile,
                               interpret=(b == "pallas_interpret"))
     return out.astype(k_pool.dtype)
 
@@ -429,17 +441,22 @@ def mla_paged_attention_quant(q_eff: Array, q_rope: Array, c_words: Array,
 
 
 def page_gather(pool: Array, page_table: Array, alive: Array, *,
-                backend: Optional[str] = None,
+                heads: int = 0, backend: Optional[str] = None,
                 mesh: Optional[Mesh] = None) -> Array:
     """Per-slot logical KV view [B, max_pages·page, ...] with dead slots
     masked to the trash page — the standalone gather (the fused decode
-    kernels above subsume it on the hot path).  On a mesh a KV pool
-    [P+1, page, KV, ·] splits its kv heads."""
+    kernels above subsume it on the hot path).  ``heads``: the number of
+    kv heads a lane-dense pool [P+1, page, heads·hd] holds side by side
+    (0: none); on a mesh such a pool splits its lanes by head, and a
+    pool [P+1, page, KV, ·] its kv-head axis."""
     b = backend or default_backend()
     m = _model_size(mesh, b)
     if m:
-        spec = _heads_spec(pool.ndim, 2,
-                           pool.ndim == 4 and pool.shape[2] % m == 0)
+        if heads:
+            spec = _heads_spec(pool.ndim, pool.ndim - 1, heads % m == 0)
+        else:
+            spec = _heads_spec(pool.ndim, 2,
+                               pool.ndim == 4 and pool.shape[2] % m == 0)
         body = functools.partial(page_gather, backend=b)
         return _shard_call(mesh, body, (spec, P(), P()), spec, pool,
                            page_table, alive)

@@ -127,19 +127,21 @@ def quantized_gather(tokens: jax.Array, pidx: jax.Array,
 @functools.partial(jax.jit,
                    static_argnames=("softcap", "scale", "token_tile",
                                     "interpret"))
-def _paged_attention_jit(q, k_pool, v_pool, page_table, pos, alive, softcap,
-                         scale, token_tile, interpret):
-    return paged_attention_pallas(q, k_pool, v_pool, page_table, pos, alive,
-                                  softcap=softcap, scale=scale,
+def _paged_attention_jit(q, k_pool, v_pool, layer, page_table, pos, alive,
+                         softcap, scale, token_tile, interpret):
+    return paged_attention_pallas(q, k_pool, v_pool, layer, page_table, pos,
+                                  alive, softcap=softcap, scale=scale,
                                   token_tile=token_tile, interpret=interpret)
 
 
-def paged_attention(q, k_pool, v_pool, page_table, pos, alive, *,
+def paged_attention(q, k_pool, v_pool, layer, page_table, pos, alive, *,
                     softcap=None, scale, token_tile=None,
                     interpret: Optional[bool] = None) -> jax.Array:
-    """Fused page-gather + online-softmax GQA decode (paged_attention.py)."""
-    return _paged_attention_jit(q, k_pool, v_pool, page_table, pos, alive,
-                                softcap, scale, token_tile,
+    """Fused page-gather + online-softmax GQA decode over one layer of
+    the stacked lane-dense pools [G, P+1, page, KV·hd]
+    (paged_attention.py)."""
+    return _paged_attention_jit(q, k_pool, v_pool, layer, page_table, pos,
+                                alive, softcap, scale, token_tile,
                                 _auto_interpret(interpret))
 
 

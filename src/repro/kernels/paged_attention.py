@@ -24,6 +24,12 @@ words in the ``pack_rows`` layout plus per-page codebooks
 ``kernels.unpack`` — KV HBM traffic is ``kv_bits/8`` bytes per cached
 scalar, the eq.-14 accounting applied to activations.
 
+Dense pages are stored lane-dense and stacked over the layers,
+``[G, P+1, page, KV·hd]``: the layer index is a fourth scalar-prefetch
+operand, a KV block is ``(1, 1, bt, KV·hd)`` and each head is a lane
+slice of it.  That is the pool's own stored layout with no padding, so
+the decode program hands the kernel the pool it updates in place.
+
 Grid: ``(B, max_pages · page_size // token_tile)`` — the token axis is
 innermost, so the per-slot accumulator scratch carries across KV tiles
 and the output block (revisited each step) is written once on the last
@@ -67,9 +73,12 @@ def _tile_valid(pos_ref, alive_ref, b, j, bt):
 # GQA (dense and quantized KV pages)
 
 
-def _gqa_body(q_ref, k, v, o_ref, m_ref, l_ref, acc_ref, *, valid, j,
+def _gqa_body(q_ref, qk, pv, o_ref, m_ref, l_ref, acc_ref, *, valid, j,
               nj, softcap, scale):
-    """Shared GQA tile step.  k/v: [bt, KV, hd] f32 (already dequant).
+    """Shared GQA tile step.  ``qk(qg)``: logits [KV, rep, bt] of the
+    grouped query qg [KV, rep, hd] against the tile's keys; ``pv(p)``:
+    the probabilities p [KV, rep, bt] times the tile's values →
+    [KV, rep, hd].
 
     Scratch: m/l [KV, rep], acc [KV, rep, hd] — the flash-softmax
     recurrence of ``chunked_attention``, with ``p`` explicitly masked:
@@ -77,13 +86,9 @@ def _gqa_body(q_ref, k, v, o_ref, m_ref, l_ref, acc_ref, *, valid, j,
     would otherwise inflate the normalizer.
     """
     h, hd = q_ref.shape[1], q_ref.shape[2]
-    kv = k.shape[1]
-    rep = h // kv
+    kv, rep = m_ref.shape
     qg = q_ref[0].reshape(kv, rep, hd).astype(jnp.float32)
-    # [KV, rep, bt]: contract hd, batch the kv-head group
-    logits = jax.lax.dot_general(
-        qg, k, (((2,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32) * scale
+    logits = qk(qg) * scale
     logits = _softcap(logits, softcap)
     ok = jnp.broadcast_to(valid, logits.shape)
     logits = jnp.where(ok, logits, NEG_INF)
@@ -93,11 +98,7 @@ def _gqa_body(q_ref, k, v, o_ref, m_ref, l_ref, acc_ref, *, valid, j,
     corr = jnp.exp(m_prev - m_new)
     m_ref[...] = m_new
     l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-    # [KV, rep, hd]: contract bt, batch the kv-head group
-    pv = jax.lax.dot_general(
-        p, v, (((2,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr[..., None] + pv
+    acc_ref[...] = acc_ref[...] * corr[..., None] + pv(p)
 
     @pl.when(j == nj - 1)
     def _():
@@ -105,22 +106,41 @@ def _gqa_body(q_ref, k, v, o_ref, m_ref, l_ref, acc_ref, *, valid, j,
         o_ref[0] = o.reshape(h, hd)
 
 
-def _gqa_kernel(tbl_ref, pos_ref, alive_ref, q_ref, k_ref, v_ref, o_ref,
-                m_ref, l_ref, acc_ref, *, bt, nj, softcap, scale):
-    del tbl_ref
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
+def _init_scratch(m_ref, l_ref, acc_ref, j):
+    """Reset the online-softmax scratch on a slot's first KV tile."""
     @pl.when(j == 0)
     def _():
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
+
+def _gqa_kernel(lyr_ref, tbl_ref, pos_ref, alive_ref, q_ref, k_ref, v_ref,
+                o_ref, m_ref, l_ref, acc_ref, *, bt, nj, softcap, scale):
+    """Dense pages: k/v blocks [1, 1, bt, KV·hd], one layer's tile read
+    lane-dense; head g is lanes [g·hd, (g+1)·hd)."""
+    del lyr_ref, tbl_ref
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    _init_scratch(m_ref, l_ref, acc_ref, j)
+    hd = q_ref.shape[2]
+    kv = m_ref.shape[0]
+    k = k_ref[0, 0].astype(jnp.float32)             # [bt, KV·hd]
+    v = v_ref[0, 0].astype(jnp.float32)
+
+    def qk(qg):                                     # → [KV, rep, bt]
+        return jnp.stack([jax.lax.dot_general(
+            qg[g], k[:, g * hd:(g + 1) * hd], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) for g in range(kv)])
+
+    def pv(p):                                      # → [KV, rep, hd]
+        return jnp.stack([jax.lax.dot_general(
+            p[g], v[:, g * hd:(g + 1) * hd], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) for g in range(kv)])
+
     valid = _tile_valid(pos_ref, alive_ref, b, j, bt)
-    _gqa_body(q_ref, k_ref[0].astype(jnp.float32),
-              v_ref[0].astype(jnp.float32), o_ref, m_ref, l_ref, acc_ref,
-              valid=valid, j=j, nj=nj, softcap=softcap, scale=scale)
+    _gqa_body(q_ref, qk, pv, o_ref, m_ref, l_ref, acc_ref, valid=valid,
+              j=j, nj=nj, softcap=softcap, scale=scale)
 
 
 def _dequant_kv_tile(words, cb, *, head_dim, bits):
@@ -143,19 +163,22 @@ def _gqa_quant_kernel(tbl_ref, pos_ref, alive_ref, q_ref, kw_ref, vw_ref,
     del tbl_ref
     b = pl.program_id(0)
     j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-
+    _init_scratch(m_ref, l_ref, acc_ref, j)
     valid = _tile_valid(pos_ref, alive_ref, b, j, bt)
     k = _dequant_kv_tile(kw_ref[0], kcb_ref[0], head_dim=head_dim,
-                         bits=bits)
+                         bits=bits)                 # [bt, KV, hd]
     v = _dequant_kv_tile(vw_ref[0], vcb_ref[0], head_dim=head_dim,
                          bits=bits)
-    _gqa_body(q_ref, k, v, o_ref, m_ref, l_ref, acc_ref, valid=valid,
+
+    def qk(qg):          # contract hd, batch the kv-head group
+        return jax.lax.dot_general(qg, k, (((2,), (2,)), ((0,), (1,))),
+                                   preferred_element_type=jnp.float32)
+
+    def pv(p):           # contract bt, batch the kv-head group
+        return jax.lax.dot_general(p, v, (((2,), (0,)), ((0,), (1,))),
+                                   preferred_element_type=jnp.float32)
+
+    _gqa_body(q_ref, qk, pv, o_ref, m_ref, l_ref, acc_ref, valid=valid,
               j=j, nj=nj, softcap=softcap, scale=scale)
 
 
@@ -168,12 +191,19 @@ def _check_tile(page_size: int, token_tile: int) -> int:
     return token_tile
 
 
-def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, alive, *,
-                           softcap=None, scale, token_tile=None,
+def paged_attention_pallas(q, k_pool, v_pool, layer, page_table, pos, alive,
+                           *, softcap=None, scale, token_tile=None,
                            interpret=False):
-    """q [B,1,H,hd]; pools [P+1, page, KV, hd] → [B, 1, H·hd] f32."""
+    """q [B,1,H,hd]; stacked lane-dense pools [G, P+1, page, KV·hd] read
+    at ``layer`` (an int32 scalar) → [B, 1, H·hd] f32.
+
+    The pools reach the kernel as they are stored: a block is one
+    layer's ``bt`` tokens of one page, every kv head side by side on the
+    lanes, so the program neither slices a layer out nor lays it out
+    again around the call."""
     b, _, h, hd = q.shape
-    _, page, kv, _ = k_pool.shape
+    _, _, page, feat = k_pool.shape
+    kv = feat // hd
     npg = page_table.shape[1]
     bt = _check_tile(page, token_tile)
     tpp = page // bt
@@ -181,19 +211,18 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, alive, *,
     rep = h // kv
 
     kv_spec = pl.BlockSpec(
-        (1, bt, kv, hd),
-        lambda b, j, tbl, pos, alv: (_page_select(alv, tbl, b, j, tpp),
-                                     j % tpp, 0, 0))
+        (1, 1, bt, feat),
+        lambda b, j, lyr, tbl, pos, alv: (
+            lyr[0], _page_select(alv, tbl, b, j, tpp), j % tpp, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, nj),
         in_specs=[
-            pl.BlockSpec((1, h, hd), lambda b, j, tbl, pos, alv: (b, 0, 0)),
+            pl.BlockSpec((1, h, hd), lambda b, j, *_: (b, 0, 0)),
             kv_spec,
             kv_spec,
         ],
-        out_specs=pl.BlockSpec((1, h, hd),
-                               lambda b, j, tbl, pos, alv: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, hd), lambda b, j, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((kv, rep), jnp.float32),
             pltpu.VMEM((kv, rep), jnp.float32),
@@ -206,7 +235,8 @@ def paged_attention_pallas(q, k_pool, v_pool, page_table, pos, alive, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      page_table.astype(jnp.int32), pos.astype(jnp.int32),
       alive.astype(jnp.int32), q.reshape(b, h, hd), k_pool, v_pool)
     return out.reshape(b, 1, h * hd)
 
@@ -304,13 +334,7 @@ def _mla_kernel(tbl_ref, pos_ref, alive_ref, qe_ref, qr_ref, c_ref, r_ref,
     del tbl_ref
     b = pl.program_id(0)
     j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-
+    _init_scratch(m_ref, l_ref, acc_ref, j)
     valid = _tile_valid(pos_ref, alive_ref, b, j, bt)
     _mla_body(qe_ref, qr_ref, c_ref[0].astype(jnp.float32),
               r_ref[0].astype(jnp.float32), o_ref, m_ref, l_ref, acc_ref,
@@ -329,13 +353,7 @@ def _mla_quant_kernel(tbl_ref, pos_ref, alive_ref, qe_ref, qr_ref, cw_ref,
     del tbl_ref
     b = pl.program_id(0)
     j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-
+    _init_scratch(m_ref, l_ref, acc_ref, j)
     valid = _tile_valid(pos_ref, alive_ref, b, j, bt)
     ckv = _dequant_lat_tile(cw_ref[0], ccb_ref[0], d=kv_lora, bits=bits)
     kr = _dequant_lat_tile(rw_ref[0], rcb_ref[0], d=rope_dim, bits=bits)

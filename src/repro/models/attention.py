@@ -408,8 +408,8 @@ def mla_decode(p, x_t, cache: MLACache, pos, *, n_heads, kv_lora, rope_dim,
 
 
 class PagedKVCache(NamedTuple):
-    k: Array          # [n_pages + 1, page, KV, hd]  (page 0 = trash)
-    v: Array
+    k: Array          # [n_pages + 1, page, KV·hd]  (page 0 = trash),
+    v: Array          # stacked [G, ...] over a stack's layers
 
 
 class PagedMLACache(NamedTuple):
@@ -418,14 +418,27 @@ class PagedMLACache(NamedTuple):
 
 
 def init_paged_kv_cache(n_pages, page_size, n_kv, head_dim, dtype):
-    z = jnp.zeros((n_pages + 1, page_size, n_kv, head_dim), dtype)
-    return PagedKVCache(k=z, v=z)
+    """Dense pages stored lane-dense: a token's kv heads side by side,
+    the layout the paged-attention kernel reads with no padding."""
+    shape = (n_pages + 1, page_size, n_kv * head_dim)
+    return PagedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
 def init_paged_mla_cache(n_pages, page_size, kv_lora, rope_dim, dtype):
     return PagedMLACache(
         c_kv=jnp.zeros((n_pages + 1, page_size, kv_lora), dtype),
         k_rope=jnp.zeros((n_pages + 1, page_size, rope_dim), dtype))
+
+
+def _slot_cells(page_table: Array, pos: Array, alive: Array,
+                page_size: int):
+    """(physical page, in-page offset) [B] each slot writes at ``pos``;
+    dead (or page-starved) slots write the trash page."""
+    b = pos.shape[0]
+    npg = page_table.shape[1]
+    pg = jnp.clip(pos // page_size, 0, npg - 1)
+    phys = page_table[jnp.arange(b), pg]
+    return jnp.where(alive, phys, 0), pos % page_size
 
 
 def _write_slot(pool: Array, page_table: Array, pos: Array, alive: Array,
@@ -435,25 +448,22 @@ def _write_slot(pool: Array, page_table: Array, pos: Array, alive: Array,
     pool [P+1, page, ...]; page_table [B, max_pages]; pos/alive [B];
     new [B, ...].  Dead (or page-starved) slots write the trash page.
     """
-    b = new.shape[0]
-    npg = page_table.shape[1]
-    pg = jnp.clip(pos // page_size, 0, npg - 1)
-    phys = page_table[jnp.arange(b), pg]
-    phys = jnp.where(alive, phys, 0)
-    return pool.at[phys, pos % page_size].set(new.astype(pool.dtype),
-                                              mode="drop")
+    phys, off = _slot_cells(page_table, pos, alive, page_size)
+    return pool.at[phys, off].set(new.astype(pool.dtype), mode="drop")
 
 
-def _gather_slots(pool: Array, page_table: Array, alive: Array) -> Array:
+def _gather_slots(pool: Array, page_table: Array, alive: Array,
+                  heads: int = 0) -> Array:
     """Logical KV view per slot: [B, max_pages·page, ...].
 
     Dead slots' table rows are masked to the trash page *before* the
     gather (dispatch routes to ``kernels.ref.gather_pages_ref`` on CPU or
     the scalar-prefetch Pallas gather on TPU), so a stalled/empty slot
     contributes one repeated trash page instead of ``max_pages``
-    arbitrary live pages to the gather footprint.
+    arbitrary live pages to the gather footprint.  ``heads``: the kv
+    heads a lane-dense pool holds (``dispatch.page_gather``).
     """
-    return dispatch.page_gather(pool, page_table, alive,
+    return dispatch.page_gather(pool, page_table, alive, heads=heads,
                                 mesh=active_mesh())
 
 
@@ -474,27 +484,36 @@ def _slot_attention(q, ck, cv, valid, *, n_heads, n_kv, head_dim,
     return o.transpose(0, 3, 1, 2, 4).reshape(b, 1, n_heads * head_dim)
 
 
-def gqa_decode_paged(p, x_t, cache: PagedKVCache, page_table, pos, alive, *,
-                     n_heads, n_kv, head_dim, page_size,
+def gqa_decode_paged(p, x_t, cache: PagedKVCache, layer, page_table, pos,
+                     alive, *, n_heads, n_kv, head_dim, page_size,
                      attn_softcap=None, rope_theta=10000.0,
                      query_scale=None):
-    """One-token GQA decode for a batch of engine slots.
+    """One-token GQA decode for a batch of engine slots, at ``layer`` of
+    a stack's pools.
 
-    x_t [B,1,D]; page_table [B, max_pages] int32; pos [B] int32 per-slot
-    write positions; alive [B] bool (dead slots: reads fully masked,
-    writes land on the trash page).
+    x_t [B,1,D]; ``cache`` the stack's pools [G, P+1, page, KV·hd], which
+    the decode program carries through its layer loop and updates in
+    place; page_table [B, max_pages] int32; pos [B] int32 per-slot write
+    positions; alive [B] bool (dead slots: reads fully masked, writes
+    land on the trash page).
     """
+    b = x_t.shape[0]
     q, k, v = _qkv(p, x_t, n_heads, n_kv, head_dim)
     posb = pos[:, None]
     q = apply_rope(q, posb, rope_theta)
     k = apply_rope(k, posb, rope_theta)
 
-    ck = _write_slot(cache.k, page_table, pos, alive, k[:, 0], page_size)
-    cv = _write_slot(cache.v, page_table, pos, alive, v[:, 0], page_size)
+    phys, off = _slot_cells(page_table, pos, alive, page_size)
+
+    def write(pool, new):
+        return pool.at[layer, phys, off].set(
+            new.reshape(b, -1).astype(pool.dtype), mode="drop")
+
+    ck, cv = write(cache.k, k), write(cache.v, v)
     scale = query_scale if query_scale is not None else head_dim ** -0.5
     # fused page-gather + online-softmax decode; the CPU ref route is the
     # verbatim former _gather_slots/_slot_attention math (bit-identical)
-    o = dispatch.paged_attention(q, ck, cv, page_table, pos, alive,
+    o = dispatch.paged_attention(q, ck, cv, layer, page_table, pos, alive,
                                  softcap=attn_softcap, scale=scale,
                                  mesh=active_mesh())
     return qmatmul(p, "wo", o), PagedKVCache(k=ck, v=cv)
@@ -717,7 +736,8 @@ def gqa_prefill_block_paged(p, x, cache: PagedKVCache, page_table, start,
                             query_scale=None):
     """One prompt block of a paged (global-attention) GQA layer.
 
-    x [B,c,D]; start: the block's first logical position (traced OK).
+    x [B,c,D]; ``cache`` one layer's lane-dense pools [P+1, page,
+    KV·hd]; start: the block's first logical position (traced OK).
     Writes the block's K/V into the slot's pages, then attends the block
     queries over the gathered page view — rows beyond ``start + c`` are
     future/garbage and mask out causally (row index == position)."""
@@ -727,10 +747,16 @@ def gqa_prefill_block_paged(p, x, cache: PagedKVCache, page_table, start,
     q = apply_rope(q, t[None, :], rope_theta)
     k = apply_rope(k, t[None, :], rope_theta)
 
-    ck = _write_block_slot(cache.k, page_table, start, alive, k, page_size)
-    cv = _write_block_slot(cache.v, page_table, start, alive, v, page_size)
-    view_k = _gather_slots(ck, page_table, alive)          # [B,cap,KV,hd]
-    view_v = _gather_slots(cv, page_table, alive)
+    ck = _write_block_slot(cache.k, page_table, start, alive,
+                           k.reshape(b, c, -1), page_size)
+    cv = _write_block_slot(cache.v, page_table, start, alive,
+                           v.reshape(b, c, -1), page_size)
+
+    def view(pool):                                        # [B,cap,KV,hd]
+        g = _gather_slots(pool, page_table, alive, heads=n_kv)
+        return g.reshape(g.shape[:2] + (n_kv, head_dim))
+
+    view_k, view_v = view(ck), view(cv)
     scale = query_scale if query_scale is not None else head_dim ** -0.5
     o = dispatch.blockwise_prefill_attention(
         q, view_k, view_v, t, jnp.arange(view_k.shape[1]),

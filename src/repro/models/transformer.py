@@ -530,8 +530,8 @@ def _gate_slot_cache(new, old, alive: Array):
     return jax.tree_util.tree_map(sel, new, old)
 
 
-def _apply_mixer_decode_slots(kind, p, x_t, cache, page_table, pos, alive,
-                              cfg):
+def _apply_mixer_decode_slots(kind, p, x_t, cache, layer, page_table, pos,
+                              alive, cfg):
     if kind.mixer == "gqa":
         if isinstance(cache, attn.QuantPagedKVCache):
             page_size = cache.k_words.shape[1]
@@ -541,10 +541,11 @@ def _apply_mixer_decode_slots(kind, p, x_t, cache, page_table, pos, alive,
                 kv_bits=cfg.kv_bits, kv_cb_mode=cfg.kv_cb_mode,
                 attn_softcap=cfg.attn_softcap, rope_theta=cfg.rope_theta,
                 query_scale=cfg.query_scale)
-        page_size = cache.k.shape[1]
+        page_size = cache.k.shape[2]            # the stack's pools, whole
         return attn.gqa_decode_paged(
-            p, x_t, cache, page_table, pos, alive, n_heads=cfg.n_heads,
-            n_kv=cfg.n_kv, head_dim=cfg.head_dim, page_size=page_size,
+            p, x_t, cache, layer, page_table, pos, alive,
+            n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.head_dim,
+            page_size=page_size,
             attn_softcap=cfg.attn_softcap, rope_theta=cfg.rope_theta,
             query_scale=cfg.query_scale)
     if kind.mixer == "gqa_local":
@@ -579,14 +580,15 @@ def _apply_mixer_decode_slots(kind, p, x_t, cache, page_table, pos, alive,
     raise ValueError(kind.mixer)
 
 
-def _apply_layer_decode_slots(kind, p, x_t, cache, page_table, pos, alive,
-                              cfg):
+def _apply_layer_decode_slots(kind, p, x_t, cache, layer, page_table, pos,
+                              alive, cfg):
     # named scopes: the device ops of each region carry them in the
     # profiler's trace (the mixer's scope is "attn" for every kind)
     with jax.named_scope("attn"):
         h = L.rms_norm(x_t, p["ln1_norm_scale"])
         out, cache = _apply_mixer_decode_slots(kind, p["mixer"], h, cache,
-                                               page_table, pos, alive, cfg)
+                                               layer, page_table, pos,
+                                               alive, cfg)
         if cfg.post_norms:
             out = L.rms_norm(out, p["post1_norm_scale"])
         x_t = x_t + out
@@ -627,19 +629,28 @@ def decode_step_slots(params, cfg: ModelConfig, caches, page_table,
 
     new_caches = []
     for spec, sp, sc in zip(cfg.stacks, params["stacks"], caches):
-        def body(carry, xs):
-            h = carry
-            gp, gc = xs
-            new_gc = {}
-            for pi, kind in enumerate(spec.pattern):
-                h, c = _apply_layer_decode_slots(
-                    kind, gp[f"pos{pi}"], h, gc[f"pos{pi}"], page_table,
-                    pos, alive, cfg)
-                new_gc[f"pos{pi}"] = c
-            return h, new_gc
+        # dense page pools ride the carry whole and are written in place
+        # at [layer, page, offset]; every other cache is sliced per layer
+        pools = {n: c for n, c in sc.items()
+                 if isinstance(c, attn.PagedKVCache)}
+        rest = {n: c for n, c in sc.items() if n not in pools}
 
-        x, nc = jax.lax.scan(body, x, (sp, sc))
-        new_caches.append(nc)
+        def body(carry, xs):
+            h, pools = carry
+            gp, gc, layer = xs
+            pools, new_gc = dict(pools), {}
+            for pi, kind in enumerate(spec.pattern):
+                n = f"pos{pi}"
+                held = pools if n in pools else gc
+                h, c = _apply_layer_decode_slots(
+                    kind, gp[n], h, held[n], layer, page_table, pos, alive,
+                    cfg)
+                (pools if n in pools else new_gc)[n] = c
+            return (h, pools), new_gc
+
+        (x, pools), nc = jax.lax.scan(
+            body, (x, pools), (sp, rest, jnp.arange(spec.groups)))
+        new_caches.append({**nc, **pools})
     with jax.named_scope("head"):
         logits = _head(params, cfg, x)
     return logits, tuple(new_caches)
@@ -846,7 +857,7 @@ def _apply_mixer_prefill_slot(kind, p, x, cache, table_row, sl, start,
                 kv_bits=cfg.kv_bits, kv_cb_mode=cfg.kv_cb_mode,
                 attn_softcap=cfg.attn_softcap, rope_theta=cfg.rope_theta,
                 query_scale=cfg.query_scale)
-        page_size = cache.k.shape[1]
+        page_size = cache.k.shape[1]            # one layer: the scan's slice
         return attn.gqa_prefill_block_paged(
             p, x, cache, table_row, start, alive, n_heads=cfg.n_heads,
             n_kv=cfg.n_kv, head_dim=cfg.head_dim, page_size=page_size,

@@ -384,10 +384,10 @@ def test_lctrainer_sharded_c_step_plan_flag_1dev():
 
 def test_engine_paged_cache_sharding_rules():
     """Page pools replicate the page axis over data (any slot's table
-    entry may point at any physical page) and shard the kv-head axis
-    over ``model``; per-slot state shards the slot axis over data like a
-    decode batch.  A fused engine decode step must run under these
-    placements on a 2×4 mesh without resharding errors."""
+    entry may point at any physical page) and split their lane-dense
+    kv-head lanes over ``model``; per-slot state shards the slot axis
+    over data like a decode batch.  A fused engine decode step must run
+    under these placements on a 2×4 mesh without resharding errors."""
     res = run_sub("""
         from repro.dist.sharding import engine_cache_shardings, param_shardings
         from repro.models.transformer import (LayerKind, ModelConfig,
@@ -403,13 +403,14 @@ def test_engine_paged_cache_sharding_rules():
         n_slots, n_pages, page = 4, 8, 4
         caches = init_paged_cache(cfg, n_slots, n_pages, page)
         sh = engine_cache_shardings(caches, mesh, n_slots=n_slots,
-                                    n_pages=n_pages)
-        pool_sh = sh[0]["pos0"].k        # [G, n_pages+1, page, kv, hd]
+                                    n_pages=n_pages, n_kv=cfg.n_kv)
+        pool_sh = sh[0]["pos0"].k        # [G, n_pages+1, page, kv·hd]
         pool_spec = tuple(pool_sh.spec)
         # the ambiguous oversubscribed case (n_pages + 1 == n_slots):
         # pool pages must still replicate, never data-shard
         amb = init_paged_cache(cfg, 4, 3, page)
-        amb_sh = engine_cache_shardings(amb, mesh, n_slots=4, n_pages=3)
+        amb_sh = engine_cache_shardings(amb, mesh, n_slots=4, n_pages=3,
+                                        n_kv=cfg.n_kv)
         amb_spec = tuple(amb_sh[0]["pos0"].k.spec)
         params = init_params(jax.random.PRNGKey(0), cfg)
         params = jax.tree_util.tree_map(jax.device_put, params,
@@ -559,8 +560,12 @@ def test_dispatch_tensor_parallel_routes_2x4():
         x = jnp.asarray(rng.randn(3, D), jnp.float32)
         toks = jnp.asarray(rng.randint(0, V, size=(2, 5)), jnp.int32)
         H, KV, hd, page, n_pages, B = 8, 4, 16, 8, 6, 2
-        kp = jnp.asarray(rng.randn(n_pages + 1, page, KV, hd), jnp.float32)
-        vp = jnp.asarray(rng.randn(n_pages + 1, page, KV, hd), jnp.float32)
+        # stacked lane-dense pools [G, P+1, page, KV·hd], read at layer 1
+        kp = jnp.asarray(rng.randn(2, n_pages + 1, page, KV * hd),
+                         jnp.float32)
+        vp = jnp.asarray(rng.randn(2, n_pages + 1, page, KV * hd),
+                         jnp.float32)
+        layer = jnp.asarray(1, jnp.int32)
         q = jnp.asarray(rng.randn(B, 1, H, hd), jnp.float32)
         table = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
         pos = jnp.asarray([13, 20], jnp.int32)
@@ -575,16 +580,17 @@ def test_dispatch_tensor_parallel_routes_2x4():
                 toks, emb_s if m else emb, cb, layout=lay, backend=b,
                 mesh=m),
             "paged": lambda m: dispatch.paged_attention(
-                q, kp_s if m else kp, vp_s if m else vp, table, pos, alive,
-                scale=0.25, backend=b, mesh=m),
+                q, kp_s if m else kp, vp_s if m else vp, layer, table, pos,
+                alive, scale=0.25, backend=b, mesh=m),
             "gather_pages": lambda m: dispatch.page_gather(
-                kp_s if m else kp, table, alive, backend=b, mesh=m),
+                kp_s[1] if m else kp[1], table, alive, heads=KV, backend=b,
+                mesh=m),
             "prefill": lambda m: dispatch.blockwise_prefill_attention(
                 qb, kb, vb, jnp.arange(16, 24), jnp.arange(24), scale=0.25,
                 backend=b, mesh=m),
         }
         emb_s = jax.device_put(emb, NamedSharding(mesh, P("model", None)))
-        heads = NamedSharding(mesh, P(None, None, "model", None))
+        heads = NamedSharding(mesh, P(None, None, None, "model"))
         kp_s, vp_s = jax.device_put(kp, heads), jax.device_put(vp, heads)
         err = {}
         for name, call in calls.items():

@@ -170,13 +170,29 @@ def test_mla_quant_ref_is_dense_ref_on_dequantized_pool(bits):
 # 3. Pallas kernels (interpret mode) vs refs
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("tile", [1, 2, 4])
-def test_gqa_pallas_interpret_matches_ref(tile):
+def _stacked(pool, layer, seed, layers=3):
+    """The engine's stored form of ``pool``: lane-dense [P+1, page,
+    KV·hd] at ``layer`` of a stack of ``layers`` distinct layers."""
+    flat = pool.reshape(pool.shape[:2] + (-1,))
+    others = _rand((layers,) + flat.shape, seed)
+    return others.at[layer].set(flat)
+
+
+@pytest.mark.parametrize("tile,layer", [
+    pytest.param(1, 1, id="1"), pytest.param(2, 1, id="2"),
+    pytest.param(4, 1, id="4"), pytest.param(2, 0, id="2-layer0"),
+    pytest.param(4, 2, id="4-layer2")])
+def test_gqa_pallas_interpret_matches_ref(tile, layer):
+    """The dense kernel reads one layer of the stacked lane-dense pools,
+    at the layer index it is given, as the ref reads that layer alone."""
     q, kp, vp, tbl, pos, alive = _gqa_case()
     want = ref.paged_attention_ref(q, kp, vp, tbl, pos, alive,
                                    softcap=None, scale=SCALE)
-    got = ops.paged_attention(q, kp, vp, tbl, pos, alive, softcap=None,
-                              scale=SCALE, token_tile=tile, interpret=True)
+    got = ops.paged_attention(q, _stacked(kp, layer, 20),
+                              _stacked(vp, layer, 21),
+                              jnp.asarray(layer, jnp.int32), tbl, pos,
+                              alive, softcap=None, scale=SCALE,
+                              token_tile=tile, interpret=True)
     np.testing.assert_allclose(np.asarray(got)[ALIVE],
                                np.asarray(want)[ALIVE],
                                rtol=2e-5, atol=2e-5)

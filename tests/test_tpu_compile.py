@@ -10,7 +10,10 @@ results or times.  The topology is described inside a module fixture —
 never at import — so that under pytest-xdist only the worker given this
 file loads libtpu, and every worker collects the same tests.
 """
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +28,7 @@ from repro.kernels import dispatch
 D, F, V, H, HD = 1024, 2816, 151936, 16, 64
 SLOTS, PAGE, PAGES_PER_SLOT, CHUNK = 4, 16, 18, 64
 POOL = SLOTS * PAGES_PER_SLOT + 1
+LAYERS = 2        # dense pools are stacked over a stack's layers
 
 
 @pytest.fixture(scope="module")
@@ -110,10 +114,10 @@ def test_paged_attention(chip, kv_bits):
     meta = [((SLOTS, PAGES_PER_SLOT), jnp.int32), ((SLOTS,), jnp.int32),
             ((SLOTS,), jnp.bool_)]
     if kv_bits == 0:
-        pool = ((POOL, PAGE, H, HD), jnp.float32)
-        _compile(chip, lambda q, k, v, t, p, a: dispatch.paged_attention(
-            q, k, v, t, p, a, scale=HD ** -0.5, backend="pallas"),
-            q, pool, pool, *meta)
+        pool = ((LAYERS, POOL, PAGE, H * HD), jnp.float32)
+        _compile(chip, lambda q, k, v, ly, t, p, a: dispatch.paged_attention(
+            q, k, v, ly, t, p, a, scale=HD ** -0.5, backend="pallas"),
+            q, pool, pool, ((), jnp.int32), *meta)
         return
     words = ((POOL, PAGE, H, kvquant.words_per(HD, kv_bits)), jnp.uint32)
     cbs = ((POOL, 1, kvquant.kv_entries(kv_bits)), jnp.float32)
@@ -176,12 +180,14 @@ def test_tensor_parallel_routes(mesh4, route):
                 t, p, c, layout=layout, backend="pallas", mesh=mesh4)
             args = (arg((1, CHUNK), jnp.int32), words, cb)
     else:
-        heads = (None, None, "model", None)
-        pool = arg((POOL, PAGE, H, HD), jnp.float32, *heads)
-        fn = lambda q, k, v, t, p, a: dispatch.paged_attention(  # noqa
-            q, k, v, t, p, a, scale=HD ** -0.5, backend="pallas",
+        # lane-dense pools split their lanes by head, as the q heads split
+        pool = arg((LAYERS, POOL, PAGE, H * HD), jnp.float32, None, None,
+                   None, "model")
+        fn = lambda q, k, v, ly, t, p, a: dispatch.paged_attention(  # noqa
+            q, k, v, ly, t, p, a, scale=HD ** -0.5, backend="pallas",
             mesh=mesh4)
-        args = (arg((SLOTS, 1, H, HD), jnp.float32, *heads), pool, pool,
+        args = (arg((SLOTS, 1, H, HD), jnp.float32, None, None, "model",
+                    None), pool, pool, arg((), jnp.int32),
                 arg((SLOTS, PAGES_PER_SLOT), jnp.int32),
                 arg((SLOTS,), jnp.int32), arg((SLOTS,), jnp.bool_))
     with mesh4:
@@ -189,3 +195,87 @@ def test_tensor_parallel_routes(mesh4, route):
     assert "tpu_custom_call" in text
     # the placement is the kernel's split: no weight or pool is gathered
     assert "all-gather" not in text
+
+
+def _hlo_ops(text):
+    """(op, element type, element count, line) of every instruction."""
+    out = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* "
+                     r"([\w-]+)\(", line)
+        if m:
+            dt, dims, op = m.groups()
+            out.append((op, dt, math.prod(int(x) for x in dims.split(",")
+                                          if x), line))
+    return out
+
+
+def test_decode_program_updates_the_pool_in_place(chip, monkeypatch):
+    """The engine's decode program at MiniCPM-2B's widths (d 2304, 36
+    heads of 64, d_ff 5760, K=16), cut to 2 layers, over 8 slots and a
+    pool of 481 pages of 16: the stacked pools ride the layer loop and
+    are written in place.  No slice, update-slice or loop fusion makes a
+    float32 array of one layer's pool or more, and the only copies that
+    large are the two the call makes on entry, one per pool, because it
+    does not own the caches it is given (they are not donated)."""
+    from repro.configs import get_config
+    from repro.core.compression import unflatten_paths
+    from repro.engine.engine import _DECODE
+    from repro.models.transformer import (LayerKind, init_paged_cache,
+                                          uniform_stack)
+
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
+    g, k, slots, pages = 2, 16, 8, 480
+    cfg = dataclasses.replace(get_config("minicpm-2b"),
+                              stacks=uniform_stack(LayerKind("gqa", "dense"),
+                                                   g))
+    d, f = cfg.d_model, cfg.d_ff
+    qd = cfg.n_heads * cfg.head_dim
+    kvd = cfg.n_kv * cfg.head_dim
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    entries = {("final_norm_scale",): sds((d,), jnp.float32)}
+
+    def packed(path, rows, cols, lead=(), order="kd"):
+        layout = PackedLayout.make(rows, cols, k, order=order)
+        head, name = path[:-1], path[-1]
+        entries[head + (f"{name}_pidx",)] = sds(lead + layout.word_shape,
+                                                jnp.uint32)
+        entries[head + (f"{name}_cb",)] = sds(lead + (k,), jnp.float32)
+        entries[head + (f"{name}_layout",)] = layout
+
+    layer = ("stacks", 0, "pos0")
+    packed(("embed_tok",), cfg.vocab, d, order="row")
+    for name in ("ln1_norm_scale", "ln2_norm_scale"):
+        entries[layer + (name,)] = sds((g, d), jnp.float32)
+    for name, rows, cols in (("wq", d, qd), ("wk", d, kvd), ("wv", d, kvd),
+                             ("wo", qd, d)):
+        packed(layer + ("mixer", name), rows, cols, (g,))
+    for name, rows, cols in (("w_in", d, f), ("w_gate", d, f),
+                             ("w_out", f, d)):
+        packed(layer + ("mlp", name), rows, cols, (g,))
+    caches = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: init_paged_cache(cfg, slots, pages, PAGE)))
+    step = (sds((slots, 96), jnp.int32), sds((slots, 1), jnp.int32),
+            sds((slots,), jnp.int32), sds((slots,), jnp.bool_),
+            sds((slots,), jnp.float32), sds((slots,), jnp.int32),
+            sds((slots, 2), jnp.uint32), sds((slots,), jnp.bool_))
+    text = _DECODE.lower(unflatten_paths(entries), cfg, caches,
+                         *step).compile().as_text()
+
+    assert "tpu_custom_call" in text
+    one_layer = (pages + 1) * PAGE * kvd
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}\n")]
+    moved = [(op, line.strip()[:160]) for op, dt, n, line in _hlo_ops(text)
+             if dt == "f32" and n >= one_layer
+             and (op in ("copy", "dynamic-slice", "dynamic-update-slice")
+                  or (op == "fusion" and "kind=kLoop" in line))]
+    stacked = f"f32[{g},{pages + 1},{PAGE},{kvd}]"
+    copies = [line for op, line in moved
+              if op == "copy" and line.split(" = ")[1].startswith(stacked)
+              and line in entry]
+    assert len(copies) == 2 and len(moved) == 2, moved
